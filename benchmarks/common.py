@@ -1,4 +1,9 @@
-"""Shared benchmark helpers: subprocess multi-device runs + timing."""
+"""Shared benchmark helpers: subprocess multi-device runs + timing.
+
+The multi-device helpers force ``grid*grid*pods`` (or ``ndev``) *CPU*
+host devices through ``XLA_FLAGS``: they are a CPU rehearsal of the
+mesh paths, and their times are XLA CPU times, not chip times.
+"""
 from __future__ import annotations
 
 import json
@@ -21,7 +26,7 @@ def run_tc_subprocess(
     extra=(),
     timeout: int = 1200,
 ) -> dict:
-    """Run tc_run in a subprocess with grid*grid*pods host devices."""
+    """Run tc_run in a subprocess with grid*grid*pods CPU host devices."""
     ndev = grid * grid * pods
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
@@ -41,7 +46,7 @@ def run_tc_subprocess(
 
 
 def run_py_subprocess(code: str, ndev: int, timeout: int = 1200) -> str:
-    """Run a python snippet with ndev host devices; return stdout."""
+    """Run a python snippet with ndev CPU host devices; return stdout."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")

@@ -15,7 +15,7 @@ _CODE = """
 import json
 from repro.core import build_plan, preprocess, rmat
 from repro.core.api import get_schedule, make_grid_mesh
-from repro.launch.roofline import HW, hlo_cost
+from repro.launch.roofline import hlo_cost, peaks_for
 build_cannon_fn = get_schedule("cannon").build_fn
 
 g, _ = preprocess(rmat({scale}, 16))
@@ -23,8 +23,9 @@ plan = build_plan(g, {q})
 fn = build_cannon_fn(plan, make_grid_mesh({q}))
 comp = fn.lower(**plan.shape_structs()).compile()
 cost = hlo_cost(comp.as_text())
-t_coll = sum(cost["collectives"].values()) / HW["link_bw"]
-t_mem = cost["bytes"] / HW["hbm_bw"]
+hw = peaks_for("TPU v5 lite")  # projected onto the v5e's peaks
+t_coll = sum(cost["collectives"].values()) / hw["link_bw"]
+t_mem = cost["bytes"] / hw["hbm_bw"]
 print(json.dumps({{"frac": t_coll / max(t_coll + t_mem, 1e-12)}}))
 """
 

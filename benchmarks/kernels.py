@@ -1,22 +1,25 @@
-"""Kernel microbenchmark: tc_tile popcount vs MXU vs jnp ref (interpret
-mode timing on CPU is directional only; the BlockSpec/VMEM structure is
-what the TPU target consumes), plus the fused-vs-search2-vs-tile
-count-kernel comparison on the dense-ish block fixture.
+"""Kernel microbenchmark: tc_tile popcount vs MXU vs jnp ref, plus the
+fused-vs-search2-vs-tile count-kernel comparison on the dense-ish block
+fixture.  On CPU the tile kernel runs in interpret mode and every time
+is directional only.
 
     python -m benchmarks.kernels [--quick]
     python -m benchmarks.kernels --smoke   # CI guard: fails if the fused
         kernel miscounts on the fixture or its warm count-side tct
         regresses more than FUSED_REGRESSION_SLACK vs search2
+
+The parent never imports JAX: the tile timings run in a child of their
+own (``--tile-timings``) and the fused fixture in ``tc_run`` children,
+so each child can hold the chip in turn.
 """
 from __future__ import annotations
 
+import os
+import subprocess
 import sys
 import tempfile
 
-import jax
-import jax.numpy as jnp
-
-from .common import csv_row, run_tc_subprocess, timeit
+from .common import REPO, csv_row, run_tc_subprocess, timeit
 
 # dense-ish block fixture: every block-pair task is a real clique
 # intersection, so the short bucket dominates and the fused panel is on
@@ -28,8 +31,28 @@ FUSED_REGRESSION_SLACK = 1.05
 
 
 def main(quick=False):
+    cmd = [sys.executable, "-m", "benchmarks.kernels", "--tile-timings"]
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    out = subprocess.run(
+        cmd + (["--quick"] if quick else []), cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=1200,
+    )
+    if out.returncode != 0:
+        raise RuntimeError(out.stdout[-1000:] + out.stderr[-1000:])
+    print(out.stdout, end="")
+    return fused_fixture(repeat=3 if quick else 5)
+
+
+def tile_timings(quick=False):
+    """tc_tile popcount / MXU kernel vs the jnp reference, in this
+    process; interpret mode everywhere but on a TPU."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.kernels.tc_tile.ops import tile_pair_count
     from repro.kernels.tc_tile.ref import tile_triple_counts_ref
+
+    interpret = jax.default_backend() != "tpu"
 
     nt, ntr = (4, 8) if quick else (16, 64)
     ka, kb, km = jax.random.split(jax.random.key(0), 3)
@@ -48,7 +71,7 @@ def main(quick=False):
     for mode in ("popcount", "mxu"):
         t = timeit(
             lambda: tile_pair_count(
-                trips, A, B, M, mode=mode, interpret=True
+                trips, A, B, M, mode=mode, interpret=interpret
             ).block_until_ready()
         )
         rows.append((f"kernels/tc_tile_{mode}", t * 1e6))
@@ -60,7 +83,6 @@ def main(quick=False):
     rows.append(("kernels/tc_tile_ref", t * 1e6))
     for name, us in rows:
         print(csv_row(name, us, f"triples={ntr}"))
-    fused_fixture(repeat=3 if quick else 5)
     return rows
 
 
@@ -145,5 +167,7 @@ def fused_smoke() -> dict:
 if __name__ == "__main__":
     if "--smoke" in sys.argv:
         fused_smoke()
+    elif "--tile-timings" in sys.argv:
+        tile_timings("--quick" in sys.argv)
     else:
         main("--quick" in sys.argv)

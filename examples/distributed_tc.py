@@ -1,8 +1,10 @@
 """End-to-end distributed triangle counting (the paper's application).
 
-Spawns itself with 16 XLA host devices and runs the 4x4 Cannon grid, the
-SUMMA rectangular schedule, the 2.5D two-pod variant, and the 1D baseline
-on the same graph — all must agree with the oracle.
+A CPU rehearsal: spawns itself with 16 forced XLA *CPU* host devices and
+runs the 4x4 Cannon grid, the SUMMA rectangular schedule, the 2.5D
+two-pod variant, and the 1D baseline on the same graph — all must agree
+with the oracle.  It does not run on the chip; ``chip_smoke.py`` is the
+chip run (one chip, or the 2x2 mesh with ``--chips 4``).
 
     PYTHONPATH=src python examples/distributed_tc.py
 """

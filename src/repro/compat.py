@@ -1,25 +1,17 @@
-"""JAX-version compatibility layer (DESIGN.md §7).
+"""Thin helpers over the JAX API surface the repo uses (DESIGN.md §7).
 
-The repo targets the mesh/SPMD API surface of jax >= 0.5 (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType``) but must
-run on jax 0.4.x where those names either do not exist or have different
-signatures (``jax.experimental.shard_map.shard_map`` with ``check_rep``,
-``jax.make_mesh`` without ``axis_types``).  Every call site in ``src/`` and
-``tests/`` goes through this module instead of touching the moving API
-directly; supporting a new jax release means updating this file only.
+The repo supports one installation: Python 3.12 with jax/jaxlib 0.9.0
+(pinned in ``pyproject.toml``).  Call sites in ``src/`` and ``tests/``
+still go through this module for the mesh/SPMD calls, so the repo's
+conventions (all-Auto mesh axes, ``check_vma`` off) live in one place:
 
-Shimmed surface:
-
-* :func:`shard_map`    — ``jax.shard_map`` | ``jax.experimental.shard_map``;
-  the ``check_vma``/``check_rep`` rename is absorbed here.
-* :func:`make_mesh`    — ``axis_types`` forwarded when supported, dropped
-  otherwise (0.4.x meshes have no axis types; all axes behave as Auto).
-* :data:`AxisType`     — real enum when available, else a stand-in with the
-  same member names so call sites never branch.
-* :func:`ppermute`     — stable today; routed here so a future signature
-  change has a single home.
-* :func:`x64_enabled` / :func:`default_count_dtype` — robust replacement
-  for the deprecated ``jax.config.read("jax_enable_x64")``.
+* :func:`shard_map`    — ``jax.shard_map`` with ``check_vma=False``.
+* :func:`make_mesh`    — ``jax.make_mesh`` with all-Auto ``axis_types``.
+* :func:`ppermute` / :func:`axis_size` — ``jax.lax`` collectives.
+* :func:`cost_analysis` — ``compiled.cost_analysis()`` as a dict.
+* :func:`x64_enabled` / :func:`default_count_dtype` /
+  :func:`canonical_count_dtype` — the count dtype under the process's
+  x64 setting.
 * :func:`check_count_overflow` — the int32 fallback guard used by
   :func:`repro.core.api.count_triangles`.
 """
@@ -29,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "AxisType",
     "axis_size",
     "canonical_count_dtype",
     "check_count_overflow",
@@ -42,123 +33,45 @@ __all__ = [
 ]
 
 
-# ----------------------------------------------------------------------
-# AxisType
-# ----------------------------------------------------------------------
-class _AxisTypeStub:
-    """Stand-in for ``jax.sharding.AxisType`` on jax < 0.5.
-
-    Member values are only ever compared/forwarded, never interpreted, so
-    plain strings suffice.  On old jax the mesh constructor ignores them.
-    """
-
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType = getattr(jax.sharding, "AxisType", _AxisTypeStub)
-
-
-# ----------------------------------------------------------------------
-# mesh construction
-# ----------------------------------------------------------------------
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` across versions.
-
-    ``axis_types`` defaults to all-Auto (the repo's convention); it is
-    forwarded on jax >= 0.5 and dropped on 0.4.x, where meshes carry no
-    axis types and every axis already behaves as Auto.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if axis_types is None:
-        axis_types = (AxisType.Auto,) * len(tuple(axis_shapes))
-    try:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names), axis_types=tuple(axis_types), **kwargs
-        )
-    except TypeError:  # jax 0.4.x: no axis_types kwarg
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-# ----------------------------------------------------------------------
-# shard_map
-# ----------------------------------------------------------------------
-_new_shard_map = getattr(jax, "shard_map", None)
-if _new_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-else:
-    _old_shard_map = None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` (>= 0.5, ``check_vma``) or the 0.4.x
-    ``jax.experimental.shard_map.shard_map`` (``check_rep``)."""
-    if _new_shard_map is not None:
-        try:
-            return _new_shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError:  # transitional releases spell it check_rep
-            return _new_shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma,
-            )
-    return _old_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with all-Auto axes (the repo's convention)."""
+    axis_shapes = tuple(axis_shapes)
+    return jax.make_mesh(
+        axis_shapes, tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_shapes),
     )
 
 
-# ----------------------------------------------------------------------
-# collectives
-# ----------------------------------------------------------------------
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    """``jax.shard_map`` with the repo's ``check_vma=False`` default."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
+    )
+
+
 def ppermute(x, axis_name, perm):
-    """``jax.lax.ppermute`` — stable across supported versions."""
+    """``jax.lax.ppermute``."""
     return jax.lax.ppermute(x, axis_name, perm=perm)
 
 
 def axis_size(axis_name) -> int:
-    """Size of a mapped mesh axis, as a static int.
-
-    ``jax.lax.axis_size`` is recent; on older jax ``psum(1, axis)`` is
-    constant-folded to the axis size at trace time.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a mapped mesh axis, as a static int."""
+    return jax.lax.axis_size(axis_name)
 
 
-# ----------------------------------------------------------------------
-# compiled-executable introspection
-# ----------------------------------------------------------------------
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as one flat dict across versions.
-
-    jax 0.4.x returns a list with one per-program dict (possibly empty);
-    jax >= 0.5 returns the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    """``compiled.cost_analysis()``; ``{}`` when the backend reports
+    nothing."""
+    return compiled.cost_analysis() or {}
 
 
 # ----------------------------------------------------------------------
 # x64 / count dtype
 # ----------------------------------------------------------------------
 def x64_enabled() -> bool:
-    """Whether 64-bit mode is on, without the deprecated config.read."""
-    try:
-        return bool(jax.config.jax_enable_x64)
-    except AttributeError:
-        try:
-            return bool(jax.config.read("jax_enable_x64"))
-        except Exception:  # noqa: BLE001 — any failure means default off
-            return False
+    """Whether 64-bit mode is on."""
+    return bool(jax.config.jax_enable_x64)
 
 
 def default_count_dtype():

@@ -79,6 +79,10 @@ class TCResult:
     # repro.runtime.supervisor.supervised_count; None on unsupervised
     # runs (DESIGN.md §8)
     supervision: Optional[dict] = None
+    # the staged plan arrays the CSR engine counted from (name -> device
+    # array); on a multi-device mesh each is sharded as the engine takes
+    # it.  None on the dense/tile operand-store paths.
+    staged: Optional[dict] = None
 
 
 def make_grid_mesh(q: int, row_axis="data", col_axis="model", npods=1, pod_axis="pod"):
@@ -167,6 +171,7 @@ class RunContext:
     autotune_mode: Optional[str] = None
     measured_table_hit: Optional[bool] = None
     artifact: Optional[object] = None  # PlanArtifact set by the runner
+    staged: Optional[dict] = None  # device arrays the engine counted from
     # set via mark_counting(): host-side planning/staging before this
     # point is reported as preprocess time, not count time
     counting_started_at: Optional[float] = None
@@ -259,6 +264,33 @@ def _consult_measured(ctx: RunContext, plan) -> Optional[dict]:
     ctx.autotune_mode = "measured"
     ctx.measured_table_hit = hit
     return entry
+
+
+def _placement(mesh, fn) -> Optional[dict]:
+    """Per-input shardings of the engine ``fn`` on a multi-device mesh;
+    ``None`` on one device, where default staging already fits."""
+    return fn.shardings if mesh.devices.size > 1 else None
+
+
+def _stage(host: dict, placement: Optional[dict]) -> dict:
+    """Host plan arrays on the device: placed by ``placement`` (only the
+    inputs it names), else on the default device."""
+    import jax
+
+    if placement is None:
+        return {k: jnp.asarray(v) for k, v in host.items()}
+    return {k: jax.device_put(host[k], s) for k, s in placement.items()}
+
+
+def _stage_plan(ctx: RunContext, plan, mesh, fn) -> dict:
+    """The plan arrays the engine ``fn`` counts from, staged through the
+    artifact's memo when there is one; recorded on ``ctx.staged``."""
+    placement = _placement(mesh, fn)
+    if ctx.artifact is not None:
+        ctx.staged = ctx.artifact.staged(placement)
+    else:
+        ctx.staged = _stage(plan.device_arrays(), placement)
+    return ctx.staged
 
 
 def _run_cannon(graph: Graph, mesh, ctx: RunContext):
@@ -384,23 +416,7 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
 
         plan = bucketize_plan(plan)
 
-    pod_axis = None
-    if ctx.npods > 1:
-        pod_axis = "pod"
-        staged = ctx.memo(
-            ("pod_staged", ctx.npods),
-            lambda: {
-                k: jnp.asarray(v)
-                for k, v in cannon_mod.pod_stack_arrays(
-                    plan.device_arrays(), ctx.npods, plan.q
-                ).items()
-            },
-        )
-    elif ctx.artifact is not None:
-        staged = ctx.artifact.staged()
-    else:
-        staged = {k: jnp.asarray(v) for k, v in plan.device_arrays().items()}
-    ctx.mark_counting(plan)
+    pod_axis = "pod" if ctx.npods > 1 else None
     fn = ctx.memo(
         ("fn", mesh, ctx.method, ctx.probe_shorter, str(ctx.count_dtype),
          pod_axis, ctx.use_step_mask, ctx.double_buffer, ctx.compact,
@@ -420,6 +436,19 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
             fused_tile=ctx.fused_tile,
         ),
     )
+    if pod_axis is not None:
+        staged = ctx.staged = ctx.memo(
+            ("pod_staged", ctx.npods, mesh),
+            lambda: _stage(
+                cannon_mod.pod_stack_arrays(
+                    plan.device_arrays(), ctx.npods, plan.q
+                ),
+                _placement(mesh, fn),
+            ),
+        )
+    else:
+        staged = _stage_plan(ctx, plan, mesh, fn)
+    ctx.mark_counting(plan)
     return int(fn(**staged)), plan
 
 
@@ -463,13 +492,6 @@ def _run_summa(graph: Graph, mesh, ctx: RunContext):
             ctx.fused_tile = entry["best"]["tile"]
     elif ctx.method == "auto":
         ctx.method = _resolve_auto_method(splan)
-    if ctx.artifact is not None:
-        staged = ctx.artifact.staged()
-    else:
-        staged = {
-            k: jnp.asarray(v) for k, v in splan.device_arrays().items()
-        }
-    ctx.mark_counting(splan)
     fn = ctx.memo(
         ("fn", mesh, ctx.method, ctx.probe_shorter, str(ctx.count_dtype),
          ctx.use_step_mask, ctx.compact, ctx.broadcast,
@@ -487,6 +509,8 @@ def _run_summa(graph: Graph, mesh, ctx: RunContext):
             fused_tile=ctx.fused_tile,
         ),
     )
+    staged = _stage_plan(ctx, splan, mesh, fn)
+    ctx.mark_counting(splan)
     return int(fn(**staged)), splan
 
 
@@ -534,13 +558,6 @@ def _run_oned(graph: Graph, mesh, ctx: RunContext):
     elif ctx.method == "auto":
         # the ring's global-id columns rule out the two-level kernel
         ctx.method = "search"
-    if ctx.artifact is not None:
-        staged = ctx.artifact.staged()
-    else:
-        staged = {
-            k: jnp.asarray(v) for k, v in oplan.device_arrays().items()
-        }
-    ctx.mark_counting(oplan)
     fn = ctx.memo(
         ("fn", flat_mesh, ctx.method, ctx.probe_shorter,
          str(ctx.count_dtype), ctx.use_step_mask, ctx.compact,
@@ -558,6 +575,8 @@ def _run_oned(graph: Graph, mesh, ctx: RunContext):
             fused_tile=ctx.fused_tile,
         ),
     )
+    staged = _stage_plan(ctx, oplan, flat_mesh, fn)
+    ctx.mark_counting(oplan)
     return int(fn(**staged)), oplan
 
 
@@ -786,6 +805,7 @@ def count_triangles(
         autotune_mode=ctx.autotune_mode,
         measured_table_hit=ctx.measured_table_hit,
         artifact=ctx.artifact,
+        staged=ctx.staged,
     )
 
 

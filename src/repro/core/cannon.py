@@ -278,7 +278,7 @@ def build_cannon_tile_fn(
     row_axis: str = "data",
     col_axis: str = "model",
     mode: str = "popcount",
-    interpret: bool = True,
+    interpret: bool,
     count_dtype=jnp.int32,
     reduce_global: bool = True,
     use_step_mask: Optional[bool] = None,
@@ -290,8 +290,8 @@ def build_cannon_tile_fn(
 
     Tile stores shift exactly like the CSR blobs; the per-(device, shift)
     active-triple lists are static (planner-joined) and drive the kernel's
-    scalar-prefetch grid.  ``interpret=True`` validates on CPU; on TPU pass
-    ``interpret=False`` to run the Mosaic-lowered kernel.  The skip mask
+    scalar-prefetch grid.  ``interpret`` has no default: ``True``
+    validates on CPU, ``False`` runs the Mosaic-lowered kernel on TPU.  The skip mask
     comes from the *CSR* plan (``plan.step_keep``); callers stage it
     alongside the tile arrays.  Under a compacted schedule the unrolled
     body selects each live step's triple list with a *static* index.

@@ -7,10 +7,12 @@ are the two CSR blocks the device holds at the current Cannon/SUMMA step.
 Paths (DESIGN.md §2):
 
 * ``dense``   — ``sum((A @ Bᵀ) ⊙ M)``; MXU-shaped; oracle + small blocks.
-* ``search``  — vectorized binary-search intersection, chunked over tasks;
-  the scalable path for hyper-sparse giant blocks.  ``probe_shorter=True``
-  probes the shorter fragment into the longer (the TPU re-expression of the
-  paper's ⟨j,i,k⟩ hash-the-longer-list rule).
+* ``search``  — vectorized intersection, chunked over tasks; the scalable
+  path for hyper-sparse giant blocks.  On CPU a row-wise binary search:
+  ``probe_shorter=True`` probes the shorter fragment into the longer (the
+  paper's ⟨j,i,k⟩ hash-the-longer-list rule).  On TPU, where every binary
+  search step is a slow element gather, fragments are fetched as
+  contiguous windows and intersected by a dense equality compare.
 * ``tile``    — bit-packed 128×128 tile kernel (``repro.kernels.tc_tile``),
   wired in by :mod:`repro.core.cannon` when the plan carries tile stores.
 * ``fused``   — the Pallas probe-gather + intersection + accumulate
@@ -95,6 +97,30 @@ def gather_rows(indptr, indices, rows, dpad: int, sentinel: int):
     return jnp.where(valid, vals, sentinel), length
 
 
+# per-chunk element bound of the TPU path's (chunk, dpad, dpad) compare
+_EQUALITY_ELEMS = 1 << 26
+
+
+def _equality_intersect() -> bool:
+    """Intersect by dense equality (TPU) instead of binary search: on a
+    TPU each binary-search step is an element gather, ~29x slower on the
+    default Cannon count of Graph500 scale 20 (one v5e chip)."""
+    return jax.default_backend() == "tpu"
+
+
+def _window_rows(indptr, indices_padded, rows, dpad: int, sentinel: int):
+    """Like :func:`gather_rows`, but each fragment is one contiguous
+    ``dpad`` window of ``indices_padded`` (``indices`` followed by
+    ``dpad`` sentinels, so no window is clamped)."""
+    start = indptr[rows]
+    length = indptr[rows + 1] - start
+    vals = jax.vmap(
+        lambda s: jax.lax.dynamic_slice(indices_padded, (s,), (dpad,))
+    )(start)
+    offs = jnp.arange(dpad, dtype=indptr.dtype)
+    return jnp.where(offs[None, :] < length[:, None], vals, sentinel), length
+
+
 def _searchsorted_rows(keys, queries):
     """Row-wise searchsorted: keys (T, Dk) sorted rows; queries (T, Dq)."""
     return jax.vmap(
@@ -122,8 +148,13 @@ def count_pair_search(
     ``ti, tj: (tmax,)`` local row ids into A / B; only the first ``tcount``
     are real (the rest are padding and masked out).  Tasks are processed in
     ``tmax / chunk`` chunks under ``lax.scan`` so the working set stays at
-    ``O(chunk * dpad)`` regardless of block size.
+    ``O(chunk * dpad)`` regardless of block size — on TPU ``O(chunk *
+    dpad²)``, with ``chunk`` shrunk to keep that under
+    ``_EQUALITY_ELEMS``.
     """
+    equality = _equality_intersect()
+    if equality:
+        chunk = max(1, min(chunk, _EQUALITY_ELEMS // (dpad * dpad)))
     tmax = ti.shape[0]
     nchunk = -(-tmax // chunk)
     pad = nchunk * chunk - tmax
@@ -137,9 +168,31 @@ def count_pair_search(
 
     if sentinel is None:
         sentinel = a_indptr.shape[0]  # nb + 1 > any local col id
+    offs = jnp.arange(dpad)
+
+    if equality:
+        def tail(indices):
+            return jnp.concatenate(
+                [indices, jnp.full((dpad,), sentinel, indices.dtype)]
+            )
+
+        a_win, b_win = tail(a_indices), tail(b_indices)
 
     def one_chunk(acc, args):
         rows_i, rows_j, valid = args
+        if equality:
+            # CSR rows are duplicate-free: equal pairs = |A ∩ B|; the
+            # sentinel padding only matches where the probe mask is off
+            a_vals, a_len = _window_rows(
+                a_indptr, a_win, rows_i, dpad, sentinel
+            )
+            b_vals, _ = _window_rows(b_indptr, b_win, rows_j, dpad, sentinel)
+            eq = (a_vals[:, :, None] == b_vals[:, None, :]) & (
+                offs[None, :, None] < a_len[:, None, None]
+            )
+            per_task = jnp.sum(eq, axis=(1, 2), dtype=count_dtype)
+            per_task = jnp.where(valid, per_task, 0)
+            return acc + jnp.sum(per_task, dtype=count_dtype), None
         a_vals, a_len = gather_rows(a_indptr, a_indices, rows_i, dpad, sentinel)
         b_vals, b_len = gather_rows(b_indptr, b_indices, rows_j, dpad, sentinel)
         if probe_shorter:
@@ -156,7 +209,7 @@ def count_pair_search(
             )
             == probe
         )
-        hit &= jnp.arange(dpad)[None, :] < probe_len[:, None]
+        hit &= offs[None, :] < probe_len[:, None]
         per_task = jnp.sum(hit, axis=1, dtype=count_dtype)
         per_task = jnp.where(valid, per_task, 0)
         return acc + jnp.sum(per_task, dtype=count_dtype), None

@@ -27,8 +27,9 @@ across ``cannon.py`` / ``summa.py`` / ``onedim.py`` live here exactly once.
 * :class:`Reduction` turns per-device per-step partials into the global
   scalar (psum over every mesh axis) or per-device outputs.
 
-All jax API calls with cross-version drift go through :mod:`repro.compat`
-so the engine runs unchanged on jax 0.4.x and >= 0.5.
+Mesh and SPMD calls go through the thin helpers of :mod:`repro.compat`,
+which hold the repo's conventions (all-Auto axes, ``check_vma`` off) for
+the one supported jax (0.9.0).
 """
 from __future__ import annotations
 
@@ -528,7 +529,7 @@ class TileStore(OperandStore):
     operand_names = ("a_tiles", "b_tiles")
     static_names = ("m_tiles", "triples")
 
-    def __init__(self, *, mode: str = "popcount", interpret: bool = True,
+    def __init__(self, *, mode: str = "popcount", interpret: bool,
                  count_dtype=jnp.int32):
         self.mode = mode
         self.interpret = interpret
@@ -1233,8 +1234,13 @@ class HubCount:
 # ======================================================================
 # engine builders
 # ======================================================================
-def _make_call(fn, ordered, in_specs):
-    """Keyword/positional call wrapper with ``.lower`` for dry runs."""
+def _make_call(fn, ordered, in_specs, shardings):
+    """Keyword/positional call wrapper with ``.lower`` for dry runs.
+
+    ``call.shardings`` maps each input name to the ``NamedSharding`` the
+    program takes it with — what :meth:`PlanArtifact.staged` places
+    arrays by, so a multi-device mesh never stages everything on its
+    first device."""
 
     def call(*pos, **arrays):
         if pos:
@@ -1248,6 +1254,7 @@ def _make_call(fn, ordered, in_specs):
 
     call.lower = lower
     call.in_specs = in_specs
+    call.shardings = shardings
     call.ordered = list(ordered)
     return call
 
@@ -1362,7 +1369,11 @@ def build_engine_fn(
             check_vma=False,
         )
     )
-    return _make_call(fn, ordered, specs)
+    shardings = {
+        k: jax.sharding.NamedSharding(mesh, spec)
+        for k, spec in zip(ordered, in_specs)
+    }
+    return _make_call(fn, ordered, specs, shardings)
 
 
 def build_engine_stepper(

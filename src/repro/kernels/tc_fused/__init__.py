@@ -3,7 +3,8 @@
 ``tc_fused`` — probe-gather + sorted-intersection + count-accumulate for
 an entire device-step in one Pallas kernel, tiled over the autotuner's
 ``d_small``/``n_long`` maxfrag split: short tasks run through a dense
-equality panel held in VMEM, long rows fall back to the chunked
+equality panel held in VMEM (fragments DMA'd from HBM-resident
+indices), long rows fall back to the chunked
 two-level global-search path.  A pure-lax reference with identical
 masking semantics backs CPU CI (and is the fast path on CPU backends).
 
@@ -17,7 +18,6 @@ from .ops import (  # noqa: F401
     VMEM_BUDGET_BYTES,
     count_pair_fused,
     fused_gate,
-    fused_panel_bytes,
     fused_tile_for,
     fused_vmem_bytes,
     resolve_fused_impl,

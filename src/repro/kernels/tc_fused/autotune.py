@@ -5,8 +5,9 @@ from the probe-length distribution without ever running a kernel.  This
 module adds the *measured* mode: time a handful of candidate
 ``(tile, chunk, d_small)`` shapes of the fused panel against the
 incumbent two-level search on the plan's busiest device block, sanity-
-check the verdict against the :mod:`repro.launch.roofline` HBM
-bandwidth ceiling, and persist the result so every later run with the
+check the verdict against the published peaks of the device it ran on
+(:data:`repro.launch.roofline.PEAKS`; no roofline verdict on a device
+kind the table does not know), and persist the result so every later run with the
 same (backend, dtype, shape-bucket) resolves ``method="auto"`` straight
 from the table.
 
@@ -38,7 +39,7 @@ from ...core.count import (
     count_pair_search,
     count_pair_search_two_level,
 )
-from ...launch.roofline import HW
+from ...launch.roofline import PEAKS
 from .ops import count_pair_fused, fused_tile_for, resolve_fused_impl
 
 __all__ = [
@@ -102,7 +103,7 @@ def measured_table_key(
 
 
 def roofline_predict(
-    *, tshort: int, d_small: int, dpad: int, nnz: int
+    *, tshort: int, d_small: int, dpad: int, nnz: int, peaks: dict
 ) -> dict:
     """Roofline time model for the short-task bucket (the long bucket
     runs the same fallback on both paths and cancels out).
@@ -110,7 +111,7 @@ def roofline_predict(
     search2's short bucket gathers the probe panel (``dpad`` ids per
     task, where ``dpad`` = the baseline's short padding) and then runs a
     binary search whose ~log2(nnz) dependent levels each touch HBM,
-    plus the key encode — all charged to ``HW['hbm_bw']``.  The fused
+    plus the key encode — all charged to ``peaks['hbm_bw']``.  The fused
     kernel's HBM traffic is the two fragment gathers ONLY: the (d, d)
     equality panel lives in VMEM/registers and never reaches HBM (the
     point of the fusion), so it is charged to the *compute* ceiling
@@ -123,14 +124,14 @@ def roofline_predict(
     bytes_fused = tshort * 2.0 * d_small * 4.0
     ops_fused = tshort * float(d_small) ** 2
     t_fused = max(
-        bytes_fused / HW["hbm_bw"], ops_fused / HW["peak_flops"]
+        bytes_fused / peaks["hbm_bw"], ops_fused / peaks["peak_flops"]
     )
-    t_search = bytes_search / HW["hbm_bw"]
+    t_search = bytes_search / peaks["hbm_bw"]
     return dict(
         t_search=t_search,
         t_fused=t_fused,
-        hbm_bw=HW["hbm_bw"],
-        peak_flops=HW["peak_flops"],
+        hbm_bw=peaks["hbm_bw"],
+        peak_flops=peaks["peak_flops"],
         predicted_winner="fused" if t_fused < t_search else "search2",
     )
 
@@ -139,6 +140,10 @@ def predict_fused_wins(entry: dict) -> bool:
     """The table's verdict: does the measured fused best beat the
     measured baseline on this shape bucket?"""
     return bool(entry.get("winner") == "fused")
+
+
+def _device_kind() -> str:
+    return jax.devices()[0].device_kind
 
 
 def _time_once(fn, *args) -> float:
@@ -297,11 +302,15 @@ def measured_entry(
 
     tshort = max(0, cnt - n_long)
     # the baseline's short bucket runs at d_small padding too (search2's
-    # dpad_short) — the paths differ in traffic pattern, not padding
-    predict = roofline_predict(
-        tshort=max(1, tshort), d_small=d_small, dpad=d_small,
-        nnz=int(b_idx.shape[0]),
-    )
+    # dpad_short) — the paths differ in traffic pattern, not padding;
+    # a device kind without published peaks gets no roofline verdict
+    kind = _device_kind()
+    predict = None
+    if kind in PEAKS:
+        predict = roofline_predict(
+            tshort=max(1, tshort), d_small=d_small, dpad=d_small,
+            nnz=int(b_idx.shape[0]), peaks=PEAKS[kind],
+        )
     entry = dict(
         version=TABLE_VERSION,
         key=key,

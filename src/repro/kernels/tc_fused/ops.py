@@ -8,10 +8,11 @@ the fused equality panel at ``d_small``.  Unlike ``search2`` the long
 bucket is *skipped entirely* when ``n_long == 0`` — no always-on long
 chunk, no aug-key traffic on panel-only steps.
 
-VMEM budget (DESIGN.md §5.1): the Pallas kernel stages both CSR index
-arrays whole plus two ``(tile, d)`` panels and a ``(tile, d, d)``
-equality intermediate.  ``fused_vmem_bytes`` accounts for all of it;
-``fused_gate`` is the one decision point: when the total exceeds
+VMEM budget (DESIGN.md §5.1): the CSR index arrays stay in HBM; the
+Pallas kernel holds two per-task DMA windows, two ``(tile, dp)`` panels
+and the equality accumulator in VMEM.  ``fused_vmem_bytes`` accounts
+for all of it; ``fused_gate`` is the one decision point: when the total
+exceeds
 ``VMEM_BUDGET_BYTES`` an ``impl="auto"`` call falls back to the lax
 reference **with a warning** while an explicit ``impl="pallas"`` fails
 loudly — and both diagnose a *hub-driven* overflow (``dmax`` dwarfing
@@ -33,13 +34,12 @@ from ...core.count import (
     count_pair_search_global,
 )
 from .ref import fused_short_ref
-from .tc_fused import fused_short_counts
+from .tc_fused import LANES, SUBLANES, fused_short_counts, fused_window_rows
 
 __all__ = [
     "VMEM_BUDGET_BYTES",
     "count_pair_fused",
     "fused_gate",
-    "fused_panel_bytes",
     "fused_tile_for",
     "fused_vmem_bytes",
     "resolve_fused_impl",
@@ -47,14 +47,14 @@ __all__ = [
 
 # leave ~4 MiB of a v5e core's ~16 MiB VMEM for double-buffering slack
 VMEM_BUDGET_BYTES = 12 * (1 << 20)
-# equality-panel working set cap: tile * d * d int32 elements
+# per-tile compare work cap: tile * d * d int32 compares
 _PANEL_BUDGET_ELEMS = 1 << 20
 _TILE_MIN, _TILE_MAX = 8, 256
 
 
 def fused_tile_for(d: int, budget_elems: int = _PANEL_BUDGET_ELEMS) -> int:
-    """Largest power-of-two tile keeping the (tile, d, d) panel in
-    budget, clamped to [8, 256]."""
+    """Largest power-of-two tile keeping the tile's ``tile * d * d``
+    compares in budget, clamped to [8, 256]."""
     cap = budget_elems // max(1, d * d)
     t = _TILE_MIN
     while t * 2 <= min(cap, _TILE_MAX):
@@ -62,14 +62,14 @@ def fused_tile_for(d: int, budget_elems: int = _PANEL_BUDGET_ELEMS) -> int:
     return t
 
 
-def fused_panel_bytes(tile: int, d: int) -> int:
-    """int32 bytes of the two gather panels + the equality intermediate."""
-    return 4 * (2 * tile * d + tile * d * d)
-
-
-def fused_vmem_bytes(npad_a: int, npad_b: int, tile: int, d: int) -> int:
-    """Whole-kernel VMEM estimate: staged CSR index arrays + panels."""
-    return 4 * (npad_a + npad_b) + fused_panel_bytes(tile, d)
+def fused_vmem_bytes(tile: int, d: int) -> int:
+    """Whole-kernel VMEM estimate in bytes: two ``(tile, nr, 128)`` DMA
+    windows (``nr`` padded to a sublane group), two ``(tile, dp)``
+    panels and the ``(tile, dp)`` accumulator, all int32."""
+    nr = fused_window_rows(d)
+    nr_pad = -(-nr // SUBLANES) * SUBLANES
+    dp = (nr - 1) * LANES
+    return 4 * tile * (2 * nr_pad * LANES + 3 * dp)
 
 
 # a long-bucket dmax this far past the panel depth is the heavy-tail
@@ -78,8 +78,6 @@ _HUB_DMAX_RATIO = 4
 
 
 def fused_gate(
-    npad_a: int,
-    npad_b: int,
     tile: int,
     d: int,
     *,
@@ -96,7 +94,7 @@ def fused_gate(
     plate — rather than by a uniformly deep graph where only a smaller
     ``d_small``/``tile`` helps.
     """
-    need = fused_vmem_bytes(npad_a, npad_b, tile, d)
+    need = fused_vmem_bytes(tile, d)
     hub_driven = (
         dmax is not None
         and d_small is not None
@@ -171,10 +169,7 @@ def count_pair_fused(
 
     resolved = resolve_fused_impl(impl)
     if resolved == "pallas":
-        gate = fused_gate(
-            a_indices.shape[0], b_indices.shape[0], tile, d,
-            dmax=dpad_long, d_small=d_small,
-        )
+        gate = fused_gate(tile, d, dmax=dpad_long, d_small=d_small)
         if not gate["fits"]:
             hint = (
                 "the overflow is hub-driven (dmax "
@@ -203,8 +198,7 @@ def count_pair_fused(
                 raise ValueError(
                     "fused panel kernel needs "
                     f"~{gate['need_bytes'] / 2**20:.1f} MiB VMEM "
-                    f"(npad_a={a_indices.shape[0]}, "
-                    f"npad_b={b_indices.shape[0]}, tile={tile}, d={d}) "
+                    f"(tile={tile}, d={d}) "
                     f"> budget {gate['budget_bytes'] / 2**20:.0f} MiB; "
                     "use impl='lax' or " + hint
                 )

@@ -9,7 +9,7 @@ __all__ = ["tile_pair_count"]
 
 
 def tile_pair_count(
-    triples, a_tiles, b_tiles, m_tiles, *, mode="popcount", interpret=True
+    triples, a_tiles, b_tiles, m_tiles, *, mode="popcount", interpret
 ):
     """Total masked-intersection count for one block pair.
 

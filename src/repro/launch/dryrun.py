@@ -16,11 +16,18 @@ Usage:
   (the sweep driver benchmarks/dryrun_sweep.py runs cells in subprocesses)
 
 Mesh names: "pod" = 16x16 (256 chips), "multipod" = 2x16x16 (512 chips).
+
+It compiles for forced *CPU* devices, so it says nothing about the TPU
+compiler; the roofline prices each cell at the published peaks of the
+chip the meshes model (``TARGET_KIND``).
 """
 import argparse
 import json
 import sys
 import traceback
+
+# the chip the pod meshes model
+TARGET_KIND = "TPU v5 lite"
 
 
 def all_cells():
@@ -96,7 +103,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str) -> dict:
             mf = model_flops_lm(cfg, shape)
         compiled = lowered.compile()
         rep = roofline_from_compiled(
-            label, compiled, mesh_name=mesh_name, chips=chips, model_flops=mf
+            label, compiled, device_kind=TARGET_KIND, mesh_name=mesh_name, chips=chips, model_flops=mf
         )
         return rep.row()
 
@@ -116,7 +123,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str) -> dict:
         lowered = fn.lower(info["dummy"], opt_shape, batch, 0)
         compiled = lowered.compile()
         rep = roofline_from_compiled(
-            label, compiled, mesh_name=mesh_name, chips=chips,
+            label, compiled, device_kind=TARGET_KIND, mesh_name=mesh_name, chips=chips,
             model_flops=_gnn_model_flops(cfg, shape),
         )
         return rep.row()
@@ -145,7 +152,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str) -> dict:
             )
         compiled = lowered.compile()
         rep = roofline_from_compiled(
-            label, compiled, mesh_name=mesh_name, chips=chips,
+            label, compiled, device_kind=TARGET_KIND, mesh_name=mesh_name, chips=chips,
             model_flops=_recsys_model_flops(cfg, shape),
         )
         return rep.row()
@@ -254,6 +261,7 @@ def _run_tc_cell(cfg, sched: str, mesh, chips: int, label: str) -> dict:
     rep = roofline_from_compiled(
         label,
         compiled,
+        device_kind=TARGET_KIND,
         mesh_name="multipod" if sched == "cannon25d" else "pod",
         chips=chips,
         model_flops=useful,

@@ -3,7 +3,7 @@
 A function (not a module-level constant) so importing this module never
 touches jax device state.  Single pod: 16x16 = 256 chips (v5e pod);
 multi-pod: 2x16x16 = 512 chips with a leading "pod" axis.  Mesh creation
-goes through :mod:`repro.compat` so it works on jax 0.4.x and >= 0.5.
+goes through :func:`repro.compat.make_mesh` (all-Auto axes).
 """
 from __future__ import annotations
 

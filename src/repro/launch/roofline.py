@@ -2,9 +2,12 @@
 
 Three terms per (arch × shape × mesh), in seconds (EXPERIMENTS.md §Roofline):
 
-    compute    = FLOPs_per_device / peak_FLOPs            (197e12 bf16, v5e)
-    memory     = bytes_per_device / HBM_bw                (819e9 B/s)
-    collective = collective_bytes_per_device / link_bw    (50e9 B/s ICI)
+    compute    = FLOPs_per_device / peak_flops
+    memory     = bytes_per_device / hbm_bw
+    collective = collective_bytes_per_device / link_bw
+
+with the device's published peaks from :data:`PEAKS`, keyed by
+``jax.Device.device_kind``.
 
 ``compiled.cost_analysis()`` reports per-device FLOPs / bytes for the SPMD
 module.  Collective bytes are NOT in cost_analysis: we parse the optimized
@@ -36,21 +39,41 @@ import re
 from typing import Dict, Optional
 
 __all__ = [
-    "HW",
+    "PEAKS",
     "collective_bytes",
     "collective_phases",
     "infer_num_devices",
+    "peaks_for",
     "roofline_from_compiled",
     "RooflineReport",
     "model_flops_lm",
 ]
 
-# TPU v5e per-chip constants (assignment-specified)
-HW = dict(
-    peak_flops=197e12,  # bf16
-    hbm_bw=819e9,  # B/s
-    link_bw=50e9,  # B/s per ICI link
-)
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" (system architecture) —
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# of chip-to-chip interconnect over 4 ICI links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": dict(
+        peak_flops=197e12,  # bf16
+        peak_int8_ops=393e12,
+        hbm_bytes=16e9,
+        hbm_bw=819e9,  # B/s
+        link_bw=50e9,  # B/s per ICI link
+    ),
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    """Published peaks of ``device_kind``; a kind not in :data:`PEAKS` is
+    an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -522,12 +545,14 @@ def roofline_from_compiled(
     name: str,
     compiled,
     *,
+    device_kind: str,
     mesh_name: str,
     chips: int,
     model_flops: float = 0.0,
     loop_multiplier: float = 1.0,
 ) -> RooflineReport:
-    """Build the 3-term report from a compiled executable.
+    """Build the 3-term report from a compiled executable, priced at the
+    peaks of ``device_kind`` (:func:`peaks_for`).
 
     All three terms come from the loop-aware ``hlo_cost`` walk (XLA's own
     cost_analysis counts while bodies once — see hlo_cost docstring); the
@@ -543,9 +568,10 @@ def roofline_from_compiled(
     ) * loop_multiplier
     coll = cost["collectives"]
     cbytes = sum(coll.values()) * loop_multiplier
-    t_c = flops / HW["peak_flops"]
-    t_m = byts / HW["hbm_bw"]
-    t_l = cbytes / HW["link_bw"]
+    hw = peaks_for(device_kind)
+    t_c = flops / hw["peak_flops"]
+    t_m = byts / hw["hbm_bw"]
+    t_l = cbytes / hw["link_bw"]
     bottleneck = max(
         [("compute", t_c), ("memory", t_m), ("collective", t_l)],
         key=lambda kv: kv[1],

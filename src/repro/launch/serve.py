@@ -44,6 +44,8 @@ def _serve_request(args, stats, label, fn):
     Returns the result, or ``None`` when the request exhausted its retry
     budget — the failure is recorded and the serving loop moves on,
     until the session-wide ``--failure-budget`` trips (``SystemExit``).
+    A session with any failed request still exits non-zero at its end
+    (:func:`_end_session`); the budget only decides how early it stops.
     A ``--verify`` mismatch is a ``SystemExit``, never retried: a wrong
     count is a correctness bug, not a transient fault.
     """
@@ -82,13 +84,20 @@ def _serve_request(args, stats, label, fn):
     return res
 
 
-def _print_request_stats(args, stats):
+def _end_session(args, stats):
+    """Print the session's request stats; exit non-zero if any request
+    failed."""
     print(
         f"supervision: {stats['ok']} ok, {stats['failed']} failed, "
         f"{stats['restarts']} restarts "
         f"(retries/request {args.request_retries}, "
         f"failure budget {args.failure_budget})"
     )
+    if stats["failed"]:
+        raise SystemExit(
+            f"{stats['failed']} of {stats['ok'] + stats['failed']} "
+            "requests failed"
+        )
 
 
 def _serve_tc(args):
@@ -140,7 +149,7 @@ def _serve_tc(args):
             else ""
         )
     )
-    _print_request_stats(args, req)
+    _end_session(args, req)
 
 
 def _serve_tc_stream(args):
@@ -202,7 +211,7 @@ def _serve_tc_stream(args):
             _maybe_verify(args, g, res.triangles)
     stats = default_cache().stats()
     print(f"plan cache: {stats['hits']} hits / {stats['misses']} misses")
-    _print_request_stats(args, req)
+    _end_session(args, req)
 
 
 def _spec_graph(spec):
@@ -252,7 +261,8 @@ def main():
                          "failed)")
     ap.add_argument("--failure-budget", type=int, default=3,
                     help="TC serving: failed rounds tolerated per "
-                         "session before the server exits")
+                         "session before the server stops early; any "
+                         "failed round makes the exit code non-zero")
     ap.add_argument("--inject-faults", default=None, metavar="SPEC",
                     help="deterministic typed fault injection across "
                          "the serving session (same grammar as tc_run; "
@@ -260,6 +270,9 @@ def main():
                          "retry/failure-budget path")
     args = ap.parse_args()
 
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.tc_graphs:
         return _serve_tc(args)
     if args.tc_stream:

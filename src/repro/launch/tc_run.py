@@ -144,6 +144,9 @@ def main():
 
     import jax
 
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     jax.config.update("jax_enable_x64", True)
     import jax.numpy as jnp
     import numpy as np

@@ -107,36 +107,50 @@ class PlanArtifact:
                 self._memo[key] = build()
             return self._memo[key]
 
-    def staged(self) -> Dict:
+    def staged(self, shardings: Optional[Dict] = None) -> Dict:
         """Device-staged (``jnp``) plan arrays, memoized (the pipeline's
         ``stage`` step); records its first-call wall time.
+
+        ``shardings`` (input name -> ``jax.sharding.Sharding``, e.g. an
+        engine fn's ``.shardings``) stages exactly those arrays, each
+        straight onto its shards of a multi-device mesh; memoized per
+        placement.  Without it every array goes to the default device.
 
         Delta-derived artifacts carry ``restage_from`` — the parent's
         host/staged array pairs — and go through the engine re-stage
         path, which keeps the parent's device buffer for every array the
-        splice left unchanged (DESIGN.md §4.7)."""
+        splice left unchanged (DESIGN.md §4.7).  Placed stagings upload
+        afresh."""
         import time
 
+        import jax
         import jax.numpy as jnp
 
         def build():
             t0 = time.perf_counter()
             handoff = self.restage_from
-            if handoff is not None:
+            host = self.device_arrays()
+            if shardings is not None:
+                out = {
+                    k: jax.device_put(host[k], s)
+                    for k, s in shardings.items()
+                }
+            elif handoff is not None:
                 from ..core.engine import restage_device_arrays
 
                 out, reused = restage_device_arrays(
-                    handoff[0], handoff[1], self.device_arrays()
+                    handoff[0], handoff[1], host
                 )
                 self.stage_seconds["stage_reused_buffers"] = float(reused)
             else:
-                out = {
-                    k: jnp.asarray(v) for k, v in self.device_arrays().items()
-                }
+                out = {k: jnp.asarray(v) for k, v in host.items()}
             self.stage_seconds["stage"] = time.perf_counter() - t0
             return out
 
-        return self.memo("staged_arrays", build)
+        if shardings is None:
+            return self.memo("staged_arrays", build)
+        key = ("staged_arrays", tuple(sorted(shardings.items())))
+        return self.memo(key, build)
 
     def release(self) -> None:
         """Drop memoized device state (staged buffers, compiled fns, tile

@@ -232,20 +232,27 @@ def test_measured_entry_requires_split(tmp_path):
         measured_entry(plan, table_dir=str(tmp_path))
 
 
-def test_roofline_prediction_matches_measurement(tmp_path):
-    """On the dense-ish bench fixture the analytic roofline and the
-    measured table must agree on the winner (and it is the fused
-    kernel — the acceptance bar the benchmark records)."""
-    from repro.kernels.tc_fused.autotune import (
-        measured_entry,
-        predict_fused_wins,
-    )
+def test_roofline_prediction_matches_measurement(tmp_path, monkeypatch):
+    """On the dense-ish bench fixture the analytic roofline, priced at
+    the v5e's published peaks, and the measured table must agree on the
+    winner (and it is the fused kernel — the acceptance bar the
+    benchmark records).  A device kind without published peaks (the
+    CPU) records no roofline verdict."""
+    from repro.kernels.tc_fused import autotune
     from repro.pipeline import plan_cannon
 
     g = graph_from_spec("cliques:3,60")
     art = plan_cannon(g, 1, chunk=512, autotune="fused")
-    entry, hit = measured_entry(art.plan, table_dir=str(tmp_path), force=True)
+    entry, hit = autotune.measured_entry(
+        art.plan, table_dir=str(tmp_path), force=True
+    )
     assert not hit
+    assert entry["roofline"] is None
+    monkeypatch.setattr(autotune, "_device_kind", lambda: "TPU v5 lite")
+    entry, _ = autotune.measured_entry(
+        art.plan, table_dir=str(tmp_path), force=True
+    )
     assert entry["winner"] == "fused"
     assert entry["roofline"]["predicted_winner"] == "fused"
-    assert predict_fused_wins(entry)
+    assert entry["roofline"]["hbm_bw"] == 819e9
+    assert autotune.predict_fused_wins(entry)

@@ -201,13 +201,13 @@ def test_residual_dmax_shrinks_under_hub_split():
 def test_fused_gate_flags_hub_driven_overflow():
     from repro.kernels.tc_fused import VMEM_BUDGET_BYTES, fused_gate
 
-    big = VMEM_BUDGET_BYTES  # npads alone blow the budget
-    over = fused_gate(big, big, 8, 4, dmax=512, d_small=4)
+    deep = VMEM_BUDGET_BYTES // 64  # an 8-task tile this deep overflows
+    over = fused_gate(8, deep, dmax=8 * deep, d_small=deep)
     assert not over["fits"] and over["hub_driven"]
     assert over["need_bytes"] > over["budget_bytes"]
-    uniform = fused_gate(big, big, 8, 4, dmax=8, d_small=4)
+    uniform = fused_gate(8, deep, dmax=deep, d_small=deep)
     assert not uniform["fits"] and not uniform["hub_driven"]
-    small = fused_gate(64, 64, 8, 4, dmax=512, d_small=4)
+    small = fused_gate(8, 4, dmax=512, d_small=4)
     assert small["fits"] and small["hub_driven"]
 
 
@@ -216,14 +216,15 @@ def test_fused_pallas_overflow_error_names_hub_split():
 
     from repro.kernels.tc_fused import VMEM_BUDGET_BYTES, count_pair_fused
 
-    npad = VMEM_BUDGET_BYTES // 4  # index arrays alone exceed the budget
+    deep = VMEM_BUDGET_BYTES // 64  # panels this deep exceed the budget
     indptr = jnp.zeros(3, jnp.int32)
-    indices = jnp.zeros(npad, jnp.int32)
+    indices = jnp.zeros(deep, jnp.int32)
     t = jnp.zeros(8, jnp.int32)
     with pytest.raises(ValueError, match="hub_split=True"):
         count_pair_fused(
             indptr, indices, indptr, indices, t, t, jnp.int32(0),
-            n_long=0, d_small=4, dpad_long=512, chunk=64, impl="pallas",
+            n_long=0, d_small=deep, dpad_long=8 * deep, chunk=64,
+            impl="pallas",
         )
 
 
@@ -234,14 +235,15 @@ def test_fused_auto_demotion_warns(monkeypatch):
 
     # force the auto resolution to "pallas" so the gate runs on CPU
     monkeypatch.setattr(ops, "resolve_fused_impl", lambda impl: "pallas")
-    npad = ops.VMEM_BUDGET_BYTES // 4
+    deep = ops.VMEM_BUDGET_BYTES // 64
     indptr = jnp.zeros(3, jnp.int32)
-    indices = jnp.zeros(npad, jnp.int32)
+    indices = jnp.zeros(deep, jnp.int32)
     t = jnp.zeros(8, jnp.int32)
     with pytest.warns(RuntimeWarning, match="demoted to the lax reference"):
         out = ops.count_pair_fused(
             indptr, indices, indptr, indices, t, t, jnp.int32(0),
-            n_long=0, d_small=4, dpad_long=512, chunk=64, impl="auto",
+            n_long=0, d_small=deep, dpad_long=8 * deep, chunk=64,
+            impl="auto",
         )
     assert int(out) == 0
 

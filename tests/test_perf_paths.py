@@ -35,6 +35,31 @@ def test_global_search_matches_flat():
     assert int(flat) == int(glob)
 
 
+@pytest.mark.parametrize("probe_shorter", [True, False])
+def test_equality_search_matches_binary_search(monkeypatch, probe_shorter):
+    """The TPU formulation of ``search`` (contiguous windows + dense
+    equality) counts exactly what the CPU binary search counts, for a
+    kernel call and for a whole ``count_triangles``."""
+    from repro.core import count as count_mod
+    from repro.core import count_triangles
+    from repro.pipeline import PlanCache
+
+    g, exp, plan = _plan()
+    a = plan.device_arrays()
+    args = [
+        jnp.asarray(a[k][0, 0])
+        for k in ("a_indptr", "a_indices", "b_indptr", "b_indices",
+                  "m_ti", "m_tj")
+    ]
+    kw = dict(dpad=plan.dmax, chunk=128, probe_shorter=probe_shorter)
+    want = [int(count_pair_search(*args, c, **kw)) for c in (0, 1, 500)]
+    monkeypatch.setattr(count_mod, "_equality_intersect", lambda: True)
+    got = [int(count_pair_search(*args, c, **kw)) for c in (0, 1, 500)]
+    assert got == want
+    res = count_triangles(g, q=1, cache=PlanCache(maxsize=0))
+    assert res.triangles == exp
+
+
 def test_aug_keys_sorted_and_unique_rows():
     _, _, plan = _plan()
     aug = np.asarray(
