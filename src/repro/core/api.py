@@ -21,8 +21,8 @@ the cache can skip it.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import time
 from typing import Callable, Dict, Optional
 
 import jax.numpy as jnp
@@ -32,6 +32,7 @@ from .. import compat
 from . import cannon as cannon_mod
 from .graph import Graph
 from .plan import TCPlan
+from .spans import count_scope, launch, span
 
 __all__ = [
     "TCResult",
@@ -172,16 +173,21 @@ class RunContext:
     measured_table_hit: Optional[bool] = None
     artifact: Optional[object] = None  # PlanArtifact set by the runner
     staged: Optional[dict] = None  # device arrays the engine counted from
-    # set via mark_counting(): host-side planning/staging before this
-    # point is reported as preprocess time, not count time
-    counting_started_at: Optional[float] = None
+    # span wall times of this count: "plan" (the tc.plan span, reported
+    # as preprocess time) and the engine call's dispatch/wait/fetch
+    seconds: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # holds the open tc.plan span until mark_counting() closes it
+    plan_span: contextlib.ExitStack = dataclasses.field(
+        default_factory=contextlib.ExitStack, repr=False
+    )
 
     def mark_counting(self, plan=None) -> None:
-        """Host planning/staging is done; counting starts now.  Also the
-        fault-injection window for this count: ``device_stage`` fires
-        here, and with a ``plan`` each live original step index fires a
-        ``step`` point before dispatch — so a fault armed at an elided
-        step never fires, composing with schedule compaction."""
+        """Host planning/staging is done; counting starts now: closes the
+        ``tc.plan`` span.  Also the fault-injection window for this
+        count: ``device_stage`` fires here, and with a ``plan`` each live
+        original step index fires a ``step`` point before dispatch — so a
+        fault armed at an elided step never fires, composing with
+        schedule compaction."""
         from ..runtime import faultinject
 
         if faultinject.is_armed():
@@ -190,7 +196,7 @@ class RunContext:
                 compacted = self.compact is not False
                 for s in faultinject.live_step_indices(plan, compacted):
                     faultinject.fire("step", step=s)
-        self.counting_started_at = time.perf_counter()
+        self.plan_span.close()
 
     def memo(self, key, build: Callable):
         """Per-artifact build-once helper (falls through when the runner
@@ -272,14 +278,16 @@ def _placement(mesh, fn) -> Optional[dict]:
     return fn.shardings if mesh.devices.size > 1 else None
 
 
-def _stage(host: dict, placement: Optional[dict]) -> dict:
-    """Host plan arrays on the device: placed by ``placement`` (only the
-    inputs it names), else on the default device."""
+def _stage(host: dict, placement: Optional[dict] = None) -> dict:
+    """Host plan arrays on the device (``tc.stage``): placed by
+    ``placement`` (only the inputs it names), else on the default
+    device."""
     import jax
 
-    if placement is None:
-        return {k: jnp.asarray(v) for k, v in host.items()}
-    return {k: jax.device_put(host[k], s) for k, s in placement.items()}
+    with span("tc.stage"):
+        if placement is None:
+            return {k: jnp.asarray(v) for k, v in host.items()}
+        return {k: jax.device_put(host[k], s) for k, s in placement.items()}
 
 
 def _stage_plan(ctx: RunContext, plan, mesh, fn) -> dict:
@@ -366,10 +374,7 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
         from .cannon import build_cannon_dense_fn
 
         dense = ctx.memo("dense_blocks", plan.dense_blocks)
-        staged = ctx.memo(
-            "dense_staged",
-            lambda: {k: jnp.asarray(v) for k, v in dense.items()},
-        )
+        staged = ctx.memo("dense_staged", lambda: _stage(dense))
         ctx.mark_counting(plan)
         fn = ctx.memo(
             ("dense_fn", mesh, ctx.use_step_mask, ctx.double_buffer,
@@ -382,7 +387,7 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
                 reduce_strategy=ctx.reduce_strategy,
             ),
         )
-        return int(fn(**staged)), plan
+        return launch(fn, staged, ctx.seconds), plan
     if ctx.method == "tile":
         import jax
 
@@ -390,10 +395,7 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
         from .tiles import build_tile_plan
 
         tp = ctx.memo("tile_plan", lambda: build_tile_plan(plan))
-        staged = ctx.memo(
-            "tile_staged",
-            lambda: {k: jnp.asarray(v) for k, v in tp.device_arrays().items()},
-        )
+        staged = ctx.memo("tile_staged", lambda: _stage(tp.device_arrays()))
         # interpret mode only off-TPU: Mosaic lowering needs real hardware,
         # and silently interpreting on TPU would be orders of magnitude slow
         interpret = jax.default_backend() != "tpu"
@@ -409,7 +411,7 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
                 compact=ctx.compact,
             ),
         )
-        return int(fn(**staged)), plan
+        return launch(fn, staged, ctx.seconds), plan
 
     if ctx.method == "search2" and not hasattr(plan, "n_long"):
         from .plan import bucketize_plan
@@ -449,7 +451,7 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
     else:
         staged = _stage_plan(ctx, plan, mesh, fn)
     ctx.mark_counting(plan)
-    return int(fn(**staged)), plan
+    return launch(fn, staged, ctx.seconds), plan
 
 
 def _run_summa(graph: Graph, mesh, ctx: RunContext):
@@ -511,7 +513,7 @@ def _run_summa(graph: Graph, mesh, ctx: RunContext):
     )
     staged = _stage_plan(ctx, splan, mesh, fn)
     ctx.mark_counting(splan)
-    return int(fn(**staged)), splan
+    return launch(fn, staged, ctx.seconds), splan
 
 
 def _run_oned(graph: Graph, mesh, ctx: RunContext):
@@ -577,7 +579,7 @@ def _run_oned(graph: Graph, mesh, ctx: RunContext):
     )
     staged = _stage_plan(ctx, oplan, flat_mesh, fn)
     ctx.mark_counting(oplan)
-    return int(fn(**staged)), oplan
+    return launch(fn, staged, ctx.seconds), oplan
 
 
 def _register_bundled():
@@ -602,6 +604,7 @@ _register_bundled()
 # ----------------------------------------------------------------------
 # top-level entry point
 # ----------------------------------------------------------------------
+@count_scope()
 def count_triangles(
     graph: Graph,
     mesh=None,
@@ -703,76 +706,79 @@ def count_triangles(
         # run its plan and reuse its staged device buffers / fn memos
         artifact = plan
         plan = artifact.plan
-    t0 = time.perf_counter()
-    if mesh is None:
-        q = q or 1
-        mesh = make_grid_mesh(q, npods=npods)
-    else:
-        names = list(mesh.axis_names)
-        if "pod" in names:
-            npods = mesh.shape["pod"]
-        q = mesh.shape[names[-1]]
+    # host planning and staging (the paper's ppt) are the tc.plan span:
+    # open from here until the runner's mark_counting(), or its end
+    seconds: Dict[str, float] = {}
+    plan_span = contextlib.ExitStack()
+    plan_span.enter_context(span("tc.plan", seconds, "plan"))
+    with plan_span:
+        if mesh is None:
+            q = q or 1
+            mesh = make_grid_mesh(q, npods=npods)
+        else:
+            names = list(mesh.axis_names)
+            if "pod" in names:
+                npods = mesh.shape["pod"]
+            q = mesh.shape[names[-1]]
 
-    if count_dtype is None:
-        count_dtype = compat.default_count_dtype()
+        if count_dtype is None:
+            count_dtype = compat.default_count_dtype()
 
-    spec = get_schedule(schedule)
-    if rebalance_trials and (plan is not None or not spec.plans_itself):
-        raise ValueError(
-            "rebalance_trials requires planning through the pipeline: "
-            "drop the caller-supplied plan and use a schedule registered "
-            "with plans_itself=True"
+        spec = get_schedule(schedule)
+        if rebalance_trials and (plan is not None or not spec.plans_itself):
+            raise ValueError(
+                "rebalance_trials requires planning through the pipeline: "
+                "drop the caller-supplied plan and use a schedule registered "
+                "with plans_itself=True"
+            )
+        from ..pipeline.hubsplit import normalize_hub_split
+
+        if normalize_hub_split(hub_split) is not None and (
+            plan is not None or not spec.plans_itself
+        ):
+            raise ValueError(
+                "hub_split requires planning through the pipeline: drop "
+                "the caller-supplied plan (it already carries — or lacks — "
+                "its hub side) and use a schedule registered with "
+                "plans_itself=True"
+            )
+        if not spec.plans_itself and (reorder or cyclic_p is not None):
+            # pre-pipeline runner contract: hand it the relabeled graph
+            from ..pipeline import relabel_stage
+
+            graph, _ = relabel_stage(graph, reorder=reorder, cyclic_p=cyclic_p)
+            reorder, cyclic_p = False, None
+        ctx = RunContext(
+            q=q,
+            npods=npods,
+            method=method,
+            chunk=chunk,
+            probe_shorter=probe_shorter,
+            count_dtype=count_dtype,
+            plan=plan,
+            use_step_mask=use_step_mask,
+            double_buffer=double_buffer,
+            compact=compact,
+            reduce_strategy=reduce_strategy,
+            broadcast=broadcast,
+            reorder=reorder,
+            cyclic_p=cyclic_p,
+            rebalance_trials=rebalance_trials,
+            hub_split=hub_split,
+            cache=cache,
+            autotune=autotune,
+            measured_dir=measured_dir,
+            fused_impl=fused_impl,
+            seconds=seconds,
+            plan_span=plan_span,
         )
-    from ..pipeline.hubsplit import normalize_hub_split
+        if artifact is not None:
+            ctx.artifact = artifact
+        from ..runtime import faultinject
 
-    if normalize_hub_split(hub_split) is not None and (
-        plan is not None or not spec.plans_itself
-    ):
-        raise ValueError(
-            "hub_split requires planning through the pipeline: drop the "
-            "caller-supplied plan (it already carries — or lacks — its "
-            "hub side) and use a schedule registered with "
-            "plans_itself=True"
-        )
-    if not spec.plans_itself and (reorder or cyclic_p is not None):
-        # pre-pipeline runner contract: hand it the relabeled graph
-        from ..pipeline import relabel_stage
-
-        graph, _ = relabel_stage(graph, reorder=reorder, cyclic_p=cyclic_p)
-        reorder, cyclic_p = False, None
-    ctx = RunContext(
-        q=q,
-        npods=npods,
-        method=method,
-        chunk=chunk,
-        probe_shorter=probe_shorter,
-        count_dtype=count_dtype,
-        plan=plan,
-        use_step_mask=use_step_mask,
-        double_buffer=double_buffer,
-        compact=compact,
-        reduce_strategy=reduce_strategy,
-        broadcast=broadcast,
-        reorder=reorder,
-        cyclic_p=cyclic_p,
-        rebalance_trials=rebalance_trials,
-        hub_split=hub_split,
-        cache=cache,
-        autotune=autotune,
-        measured_dir=measured_dir,
-        fused_impl=fused_impl,
-    )
-    if artifact is not None:
-        ctx.artifact = artifact
-    from ..runtime import faultinject
-
-    with faultinject.armed(fault_plan):
-        total, out_plan = spec.runner(graph, mesh, ctx)
+        with faultinject.armed(fault_plan):
+            total, out_plan = spec.runner(graph, mesh, ctx)
     total = compat.check_count_overflow(total, count_dtype)
-    t2 = time.perf_counter()
-    # host-side planning/staging counts as preprocessing (paper's ppt),
-    # like the pre-engine code; counting starts at the runner's mark
-    t1 = ctx.counting_started_at or t0
 
     hub_side = getattr(out_plan, "hub", None)
     hub_rep = None
@@ -795,8 +801,10 @@ def count_triangles(
     return TCResult(
         triangles=total,
         plan=out_plan,
-        preprocess_seconds=t1 - t0,
-        count_seconds=t2 - t1,
+        preprocess_seconds=seconds["plan"],
+        count_seconds=sum(
+            seconds.get(k, 0.0) for k in ("dispatch", "wait", "fetch")
+        ),
         method=ctx.method,  # "auto" reports its per-schedule resolution
         schedule=schedule,
         grid=(npods, q, q) if npods > 1 else (q, q),
@@ -809,6 +817,7 @@ def count_triangles(
     )
 
 
+@count_scope()
 def count_triangles_delta(
     graph: Graph,
     delta,
@@ -830,7 +839,9 @@ def count_triangles_delta(
     artifact, reusing unchanged device buffers and compiled engines.
     The result's ``delta`` field carries the apply report and its
     ``artifact`` the derived artifact for the next round; ``triangles``
-    is exact — identical to a cold count of the mutated graph.
+    is exact — identical to a cold count of the mutated graph.  The
+    whole call is one ``tc.count``; the splice runs in a ``tc.plan`` of
+    its own, before the count's.
     """
     from ..pipeline.delta import apply_delta
 
@@ -849,9 +860,10 @@ def count_triangles_delta(
                 "(schedule registered with plans_itself=True and no "
                 "caller-supplied raw plan)"
             )
-    art2 = apply_delta(
-        artifact, delta, cache=cache, rebase_every=rebase_every
-    )
+    with span("tc.plan"):
+        art2 = apply_delta(
+            artifact, delta, cache=cache, rebase_every=rebase_every
+        )
     # the derived artifact already fixed its relabeling, rebalance seed
     # and hub cut at plan time — re-count kwargs that would re-plan are
     # dropped (hub_split included: the derived plan either carries its

@@ -724,11 +724,16 @@ def masked_count(store, state, local, step, ctx, step_keep, count_dtype):
     exchange even when its own count is skipped, so the SPMD program
     stays uniform.
     """
+
+    def count():
+        with jax.named_scope("tc_count"):
+            return store.count(state, local, step, ctx)
+
     if step_keep is None:
-        return store.count(state, local, step, ctx)
+        return count()
     return jax.lax.cond(
         step_keep[step],
-        lambda: store.count(state, local, step, ctx),
+        count,
         lambda: jnp.zeros((), jnp.dtype(count_dtype)),
     )
 
@@ -1331,7 +1336,7 @@ def build_engine_fn(
             "compaction would need their union)"
         )
 
-        def spmd(*args):
+        def tc_engine_many(*args):
             named = dict(zip(ordered, args))
             keep = named.pop(MASK_NAME, None)
             # strip the size-1 mesh block dims that follow the batch axis
@@ -1345,11 +1350,12 @@ def build_engine_fn(
                 )
             return jax.lax.map(core, local)
 
+        body = tc_engine_many
         in_specs = tuple(P(None, *specs[k]) for k in ordered)
         out_specs = P(None)
     else:
 
-        def spmd(*args):
+        def tc_engine(*args):
             named = dict(zip(ordered, args))
             keep = named.pop(MASK_NAME, None)
             local = store.localize(named, axes)
@@ -1357,12 +1363,14 @@ def build_engine_fn(
                 local[MASK_NAME] = _squeeze(keep, mask_lead)
             return core(local)
 
+        body = tc_engine
         in_specs = tuple(specs[k] for k in ordered)
         out_specs = reduction.out_specs(axes)
 
+    # the function's name names the device program: jit_tc_engine...
     fn = jax.jit(
         compat.shard_map(
-            spmd,
+            body,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
@@ -1442,7 +1450,7 @@ def build_engine_stepper(
     mask_specs = (P(*axes.all),) if use_step_mask else ()
 
     def _make_fn(hop: int):
-        def spmd(*args):
+        def tc_engine_step(*args):
             carry_leaves = [_squeeze(a, lead) for a in args[:n_state]]
             pos = n_state
             statics = dict(
@@ -1467,7 +1475,7 @@ def build_engine_stepper(
 
         return jax.jit(
             compat.shard_map(
-                spmd,
+                tc_engine_step,
                 mesh=mesh,
                 in_specs=(op_spec,) * n_state + static_specs + mask_specs
                 + (op_spec, P()),
@@ -1483,7 +1491,7 @@ def build_engine_stepper(
             fns[hop] = _make_fn(hop)
         return fns[hop]
 
-    def spmd_prime(*args):
+    def tc_engine_step_prime(*args):
         local = store.localize(dict(zip(op_names, args)), axes)
         carry0 = schedule.init_carry(store, local, ctx)
         leaves = jax.tree.flatten(carry0)[0]
@@ -1495,7 +1503,7 @@ def build_engine_stepper(
 
     prime_fn = jax.jit(
         compat.shard_map(
-            spmd_prime,
+            tc_engine_step_prime,
             mesh=mesh,
             in_specs=tuple(specs[k] for k in op_names),
             out_specs=(op_spec,) * n_state,

@@ -249,4 +249,5 @@ def _fused_call(frags, a_rows, b_rows, *, d, interpret):
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
+        name="tc_count_fused",
     )(frags, a_rows, b_rows)

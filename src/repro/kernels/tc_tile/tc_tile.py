@@ -162,6 +162,6 @@ def tile_triple_counts(
     with jax.enable_x64(False):
         out = pl.pallas_call(
             kern, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret, name="tc_count_tile",
         )(flat, a_tiles, b_t, m_tiles)
     return step_counts(out, g)

@@ -63,6 +63,9 @@ class PlanArtifact:
     restage_from: Optional[Tuple[Dict, Dict]] = dataclasses.field(
         default=None, repr=False
     )
+    # device buffers the re-stage kept from the parent artifact (0 on a
+    # fresh upload)
+    reused_buffers: int = 0
     _memo: Dict = dataclasses.field(default_factory=dict, repr=False)
     _memo_lock: threading.Lock = dataclasses.field(
         default_factory=threading.Lock, repr=False
@@ -109,7 +112,8 @@ class PlanArtifact:
 
     def staged(self, shardings: Optional[Dict] = None) -> Dict:
         """Device-staged (``jnp``) plan arrays, memoized (the pipeline's
-        ``stage`` step); records its first-call wall time.
+        ``stage`` step); its first call is the ``tc.stage`` span, whose
+        wall time lands in ``stage_seconds["stage"]``.
 
         ``shardings`` (input name -> ``jax.sharding.Sharding``, e.g. an
         engine fn's ``.shardings``) stages exactly those arrays, each
@@ -121,31 +125,28 @@ class PlanArtifact:
         path, which keeps the parent's device buffer for every array the
         splice left unchanged (DESIGN.md §4.7).  Placed stagings upload
         afresh."""
-        import time
-
         import jax
         import jax.numpy as jnp
 
-        def build():
-            t0 = time.perf_counter()
-            handoff = self.restage_from
-            host = self.device_arrays()
-            if shardings is not None:
-                out = {
-                    k: jax.device_put(host[k], s)
-                    for k, s in shardings.items()
-                }
-            elif handoff is not None:
-                from ..core.engine import restage_device_arrays
+        from ..core.spans import span
 
-                out, reused = restage_device_arrays(
-                    handoff[0], handoff[1], host
-                )
-                self.stage_seconds["stage_reused_buffers"] = float(reused)
-            else:
-                out = {k: jnp.asarray(v) for k, v in host.items()}
-            self.stage_seconds["stage"] = time.perf_counter() - t0
-            return out
+        def build():
+            with span("tc.stage", self.stage_seconds, "stage"):
+                handoff = self.restage_from
+                host = self.device_arrays()
+                if shardings is not None:
+                    return {
+                        k: jax.device_put(host[k], s)
+                        for k, s in shardings.items()
+                    }
+                if handoff is not None:
+                    from ..core.engine import restage_device_arrays
+
+                    out, self.reused_buffers = restage_device_arrays(
+                        handoff[0], handoff[1], host
+                    )
+                    return out
+                return {k: jnp.asarray(v) for k, v in host.items()}
 
         if shardings is None:
             return self.memo("staged_arrays", build)

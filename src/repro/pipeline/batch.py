@@ -16,13 +16,13 @@ The padding overhead of batching is measured and reported
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 from .. import compat
 from ..core.graph import Graph
+from ..core.spans import count_scope, launch, span
 from .cache import PlanCache, default_cache, graph_digest
 from .planner import relabel_cached
 from .stages import pack_oned_plan, pack_summa_plan, pack_tc_plan
@@ -83,37 +83,36 @@ def _padding_overhead(stacked: Dict, plans) -> float:
     return float(batched / max(1, single) - 1.0)
 
 
+def _lifted(graphs, digests, *, reorder, cyclic_p, cache) -> List[Graph]:
+    """Relabel each graph on its own vertex set (degree order must not
+    see the padding vertices), then lift all graphs to the shared n."""
+    relabeled = [
+        relabel_cached(
+            g, d, reorder=reorder, cyclic_p=cyclic_p, cache=cache
+        )[0]
+        for g, d in zip(graphs, digests)
+    ]
+    n_max = max(g.n for g in relabeled)
+    return [
+        g if g.n == n_max else Graph(n=n_max, edges=g.edges, name=g.name)
+        for g in relabeled
+    ]
+
+
 def _build_batch_program(
-    graphs: Sequence[Graph],
+    lifted: Sequence[Graph],
     mesh,
     *,
     q: int,
     schedule: str,
     method: str,
     chunk: int,
-    reorder: bool,
-    cyclic_p: Optional[int],
     probe_shorter: bool,
     count_dtype,
-    cache: PlanCache,
-) -> _BatchProgram:
-    import jax.numpy as jnp
-
-    # relabel each graph on its own vertex set (degree order must not see
-    # the padding vertices), then lift all graphs to the shared n.
-    relabeled = [
-        relabel_cached(
-            g, graph_digest(g), reorder=reorder, cyclic_p=cyclic_p,
-            cache=cache,
-        )[0]
-        for g in graphs
-    ]
-    n_max = max(g.n for g in relabeled)
-    lifted = [
-        g if g.n == n_max else Graph(n=n_max, edges=g.edges, name=g.name)
-        for g in relabeled
-    ]
-
+):
+    """Pack the lifted graphs on shared shapes and build the batched
+    engine: ``(fn, stacked host arrays, grid, padding overhead)``."""
+    n_max = lifted[0].n
     if schedule == "cannon":
         from ..core.cannon import build_cannon_fn
         from ..core.plan import bucketize_plan
@@ -235,13 +234,32 @@ def _build_batch_program(
             f"got {schedule!r}"
         )
 
-    overhead = _padding_overhead(stacked, plans)
-    staged = {k: jnp.asarray(v) for k, v in stacked.items()}
+    return fn, stacked, grid, _padding_overhead(stacked, plans)
+
+
+def _plan_batch(
+    graphs, digests, mesh, *, reorder, cyclic_p, cache, **build
+) -> _BatchProgram:
+    """A batch's program on a plan-cache miss: relabel, pack and build,
+    then stage the stacked arrays."""
+    import jax.numpy as jnp
+
+    with span("tc.plan.relabel"):
+        lifted = _lifted(
+            graphs, digests, reorder=reorder, cyclic_p=cyclic_p, cache=cache
+        )
+    with span("tc.plan.pack"):
+        fn, stacked, grid, overhead = _build_batch_program(
+            lifted, mesh, **build
+        )
+    with span("tc.stage"):
+        staged = {k: jnp.asarray(v) for k, v in stacked.items()}
     return _BatchProgram(
         fn=fn, staged=staged, grid=grid, padding_overhead=overhead
     )
 
 
+@count_scope()
 def count_triangles_many(
     graphs: Sequence[Graph],
     mesh=None,
@@ -276,43 +294,43 @@ def count_triangles_many(
     from ..runtime import faultinject
 
     faultinject.fire("plan_stage", kind="many")
-    t0 = time.perf_counter()
-    if mesh is None:
-        from ..core.api import make_grid_mesh
+    seconds: Dict[str, float] = {}
+    with span("tc.plan", seconds, "plan"):
+        if mesh is None:
+            from ..core.api import make_grid_mesh
 
-        q = q or 1
-        mesh = make_grid_mesh(q)
-    else:
-        names = list(mesh.axis_names)
-        q = mesh.shape[names[-1]]
-    if count_dtype is None:
-        count_dtype = compat.default_count_dtype()
-    cache = cache if cache is not None else default_cache()
+            q = q or 1
+            mesh = make_grid_mesh(q)
+        else:
+            names = list(mesh.axis_names)
+            q = mesh.shape[names[-1]]
+        if count_dtype is None:
+            count_dtype = compat.default_count_dtype()
+        cache = cache if cache is not None else default_cache()
 
-    digests = tuple(graph_digest(g) for g in graphs)
-    key = (
-        "many", schedule, method, mesh, q, chunk, reorder, cyclic_p,
-        probe_shorter, str(np.dtype(count_dtype)), digests,
-    )
-    prog = cache.get(key)
-    cache_hit = prog is not None
-    if not cache_hit:
-        prog = _build_batch_program(
-            graphs, mesh,
-            q=q, schedule=schedule, method=method, chunk=chunk,
-            reorder=reorder, cyclic_p=cyclic_p,
-            probe_shorter=probe_shorter, count_dtype=count_dtype,
-            cache=cache,
+        with span("tc.plan.digest"):
+            digests = tuple(graph_digest(g) for g in graphs)
+        key = (
+            "many", schedule, method, mesh, q, chunk, reorder, cyclic_p,
+            probe_shorter, str(np.dtype(count_dtype)), digests,
         )
-        cache.put(key, prog)
-    t1 = time.perf_counter()
+        prog = cache.get(key)
+        cache_hit = prog is not None
+        if not cache_hit:
+            prog = _plan_batch(
+                graphs, digests, mesh,
+                q=q, schedule=schedule, method=method, chunk=chunk,
+                reorder=reorder, cyclic_p=cyclic_p,
+                probe_shorter=probe_shorter, count_dtype=count_dtype,
+                cache=cache,
+            )
+            cache.put(key, prog)
 
     faultinject.fire("device_stage")
-    totals = np.asarray(prog.fn(**prog.staged))
+    totals = launch(prog.fn, prog.staged, seconds, fetch=np.asarray)
     counts = [
         compat.check_count_overflow(int(t), count_dtype) for t in totals
     ]
-    t2 = time.perf_counter()
 
     return ManyResult(
         triangles=counts,
@@ -320,8 +338,8 @@ def count_triangles_many(
         method=method,
         grid=prog.grid,
         batch=len(graphs),
-        plan_seconds=t1 - t0,
-        count_seconds=t2 - t1,
+        plan_seconds=seconds["plan"],
+        count_seconds=seconds["dispatch"] + seconds["wait"] + seconds["fetch"],
         padding_overhead=prog.padding_overhead,
         cache_hit=cache_hit,
     )

@@ -39,7 +39,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from typing import Optional, Tuple
 
 import numpy as np
@@ -53,6 +52,7 @@ from ..core.plan import (
     compact_live_steps,
     host_aug_keys,
 )
+from ..core.spans import span
 from .artifact import PlanArtifact
 from .cache import PlanCache, default_cache
 from .hubsplit import hubsplit_stage
@@ -253,15 +253,32 @@ def apply_delta(
         hit.cache_hit = True
         return hit
 
-    t0 = time.perf_counter()
+    seconds = {}
+    with span("tc.plan.delta", seconds, "apply_delta"):
+        art = _derive(
+            artifact, delta, cfg, cache, key, lineage, chain, rebase_every,
+            dirty_limit,
+        )
+    art.key = key
+    if art.delta_report["level"] != "noop":  # a noop shares its parent's
+        art.stage_seconds.update(seconds)  # dict of stage times
+    cache.put(key, art)
+    return art
+
+
+def _derive(
+    artifact, delta, cfg, cache, key, lineage, chain, rebase_every,
+    dirty_limit,
+):
+    """``apply_delta``'s re-plan ladder, after the cache lookup: the
+    derived artifact, its ``key`` still to be set."""
     d2 = delta.relabeled(artifact.perm)
     g2, eff_add, eff_rem = _merge(artifact.graph, d2)
     eff = np.concatenate([eff_add, eff_rem], axis=0)
 
     if eff.shape[0] == 0:
-        art = dataclasses.replace(
+        return dataclasses.replace(
             artifact,
-            key=key,
             cache_hit=False,
             lineage=dict(lineage, chain=chain),
             delta_report=_report(
@@ -269,8 +286,6 @@ def apply_delta(
                 eff_add, eff_rem, True,
             ),
         )
-        cache.put(key, art)
-        return art
 
     depth = int(lineage["depth"]) + 1
     hub_side = getattr(artifact.plan, "hub", None)
@@ -308,9 +323,6 @@ def apply_delta(
             )
             if splice_refused is not None:
                 art.delta_report["reason"] = splice_refused
-    art.key = key
-    art.stage_seconds["apply_delta"] = time.perf_counter() - t0
-    cache.put(key, art)
     return art
 
 

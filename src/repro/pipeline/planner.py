@@ -10,11 +10,11 @@ different schedules planning the same graph share the degree ordering.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 from ..core.graph import Graph
 from ..core.plan import bucketize_plan
+from ..core.spans import span
 from .artifact import PlanArtifact
 from .cache import PlanCache, default_cache, graph_digest
 from .hubsplit import hubsplit_stage, normalize_hub_split
@@ -63,10 +63,8 @@ def _rebalanced(g2, perm, trials, reorder, pack_trial, seconds):
             "rebalance_trials requires reorder=True: trial relabelings "
             "shuffle within equal-degree runs of the degree ordering"
         )
-    t0 = time.perf_counter()
-    g2, perm, best_plan, report = rebalance_stage(g2, perm, trials, pack_trial)
-    seconds["rebalance"] = time.perf_counter() - t0
-    return g2, perm, best_plan, report
+    with span("tc.plan.rebalance", seconds, "rebalance"):
+        return rebalance_stage(g2, perm, trials, pack_trial)
 
 
 def _hub_knob(hub_split, reorder, cyclic_p):
@@ -97,10 +95,8 @@ def _hub_stage(g2, grid, hub_c, chunk, seconds):
     """Run the hub-split stage (no-op when off / nothing crosses)."""
     if hub_c is None:
         return g2, None
-    t0 = time.perf_counter()
-    g2, hub = hubsplit_stage(g2, grid, c=hub_c, chunk=chunk)
-    seconds["hubsplit"] = time.perf_counter() - t0
-    return g2, hub
+    with span("tc.plan.hubsplit", seconds, "hubsplit"):
+        return hubsplit_stage(g2, grid, c=hub_c, chunk=chunk)
 
 
 def _drive(kind, graph, key_tail, cache, pack):
@@ -110,9 +106,8 @@ def _drive(kind, graph, key_tail, cache, pack):
     faultinject.fire("plan_stage", kind=kind)
     cache = cache if cache is not None else default_cache()
     seconds = {}
-    t0 = time.perf_counter()
-    digest = graph_digest(graph)
-    seconds["ingest"] = time.perf_counter() - t0
+    with span("tc.plan.digest", seconds, "ingest"):
+        digest = graph_digest(graph)
 
     key = (kind, digest) + key_tail
     art = cache.get(key)
@@ -176,11 +171,11 @@ def plan_cannon(
     hub_c = _hub_knob(hub_split, reorder, cyclic_p)
 
     def pack(digest, key, seconds, cache_):
-        t0 = time.perf_counter()
-        g2, perm = relabel_cached(
-            graph, digest, reorder=reorder, cyclic_p=cyclic_p, cache=cache_
-        )
-        seconds["relabel"] = time.perf_counter() - t0
+        with span("tc.plan.relabel", seconds, "relabel"):
+            g2, perm = relabel_cached(
+                graph, digest, reorder=reorder, cyclic_p=cyclic_p,
+                cache=cache_,
+            )
         g2, hub = _hub_stage(g2, (q, q), hub_c, chunk, seconds)
         g2, perm, best_plan, rb = _rebalanced(
             g2, perm, rebalance_trials, reorder,
@@ -190,35 +185,34 @@ def plan_cannon(
             ),
             seconds,
         )
-        t1 = time.perf_counter()
-        pack_kwargs = dict(
-            skew=skew,
-            chunk=chunk,
-            with_stats=with_stats,
-            keep_blocks=keep_blocks or bucketize,
-            step_masks=step_masks,
-            aug_keys=aug_keys,
-        )
-        if best_plan is not None and (
-            with_stats and not (keep_blocks or bucketize) and step_masks
-            and not aug_keys
-        ):  # caller flags == trial flags: the winner pack is the plan
-            plan = best_plan
-        else:
-            plan = pack_tc_plan(g2, q, **pack_kwargs)
-        if compact and skew:
-            plan = compact_stage(
-                plan,
-                repack=lambda sigma: pack_tc_plan(
-                    g2, q, skew_perm=sigma, **pack_kwargs
-                ),
+        with span("tc.plan.pack", seconds, "decompose+pack"):
+            pack_kwargs = dict(
+                skew=skew,
+                chunk=chunk,
+                with_stats=with_stats,
+                keep_blocks=keep_blocks or bucketize,
+                step_masks=step_masks,
+                aug_keys=aug_keys,
             )
-        if bucketize:
-            plan = bucketize_plan(plan, d_small=d_small)
-        if autotune:
-            plan = autotune_tc_plan(plan, two_sided=(autotune == "fused"))
-        plan.hub = hub
-        seconds["decompose+pack"] = time.perf_counter() - t1
+            if best_plan is not None and (
+                with_stats and not (keep_blocks or bucketize) and step_masks
+                and not aug_keys
+            ):  # caller flags == trial flags: the winner pack is the plan
+                plan = best_plan
+            else:
+                plan = pack_tc_plan(g2, q, **pack_kwargs)
+            if compact and skew:
+                plan = compact_stage(
+                    plan,
+                    repack=lambda sigma: pack_tc_plan(
+                        g2, q, skew_perm=sigma, **pack_kwargs
+                    ),
+                )
+            if bucketize:
+                plan = bucketize_plan(plan, d_small=d_small)
+            if autotune:
+                plan = autotune_tc_plan(plan, two_sided=(autotune == "fused"))
+            plan.hub = hub
         art_graph = g2
         if hub is not None:
             # the plan arrays cover only the residual; the artifact must
@@ -277,11 +271,11 @@ def plan_summa(
     hub_c = _hub_knob(hub_split, reorder, cyclic_p)
 
     def pack(digest, key, seconds, cache_):
-        t0 = time.perf_counter()
-        g2, perm = relabel_cached(
-            graph, digest, reorder=reorder, cyclic_p=cyclic_p, cache=cache_
-        )
-        seconds["relabel"] = time.perf_counter() - t0
+        with span("tc.plan.relabel", seconds, "relabel"):
+            g2, perm = relabel_cached(
+                graph, digest, reorder=reorder, cyclic_p=cyclic_p,
+                cache=cache_,
+            )
         g2, hub = _hub_stage(g2, (r, c), hub_c, chunk, seconds)
         g2, perm, best_plan, rb = _rebalanced(
             g2, perm, rebalance_trials, reorder,
@@ -290,21 +284,22 @@ def plan_summa(
             ),
             seconds,
         )
-        t1 = time.perf_counter()
-        if best_plan is not None and step_masks:
-            plan = best_plan  # caller flags == trial flags
-        else:
-            plan = pack_summa_plan(
-                g2, r, c, chunk=chunk, step_masks=step_masks,
-                with_stats=bool(rebalance_trials),
-            )
-        if compact:
-            plan = compact_stage(plan)  # rounds have no free visit order
-        if autotune:
-            plan = autotune_summa_plan(plan, two_sided=(autotune == "fused"))
-        plan.broadcast = broadcast
-        plan.hub = hub
-        seconds["decompose+pack"] = time.perf_counter() - t1
+        with span("tc.plan.pack", seconds, "decompose+pack"):
+            if best_plan is not None and step_masks:
+                plan = best_plan  # caller flags == trial flags
+            else:
+                plan = pack_summa_plan(
+                    g2, r, c, chunk=chunk, step_masks=step_masks,
+                    with_stats=bool(rebalance_trials),
+                )
+            if compact:
+                plan = compact_stage(plan)  # rounds have no free visit order
+            if autotune:
+                plan = autotune_summa_plan(
+                    plan, two_sided=(autotune == "fused")
+                )
+            plan.broadcast = broadcast
+            plan.hub = hub
         art_graph = g2
         if hub is not None:
             hub.aligned = rb is None or int(rb.get("best_seed", 0)) == 0
@@ -351,11 +346,11 @@ def plan_oned(
     hub_c = _hub_knob(hub_split, reorder, cyclic_p)
 
     def pack(digest, key, seconds, cache_):
-        t0 = time.perf_counter()
-        g2, perm = relabel_cached(
-            graph, digest, reorder=reorder, cyclic_p=cyclic_p, cache=cache_
-        )
-        seconds["relabel"] = time.perf_counter() - t0
+        with span("tc.plan.relabel", seconds, "relabel"):
+            g2, perm = relabel_cached(
+                graph, digest, reorder=reorder, cyclic_p=cyclic_p,
+                cache=cache_,
+            )
         g2, hub = _hub_stage(g2, (p,), hub_c, chunk, seconds)
         g2, perm, best_plan, rb = _rebalanced(
             g2, perm, rebalance_trials, reorder,
@@ -364,20 +359,21 @@ def plan_oned(
             ),
             seconds,
         )
-        t1 = time.perf_counter()
-        if best_plan is not None and step_masks:
-            plan = best_plan  # caller flags == trial flags
-        else:
-            plan = pack_oned_plan(
-                g2, p, chunk=chunk, step_masks=step_masks,
-                with_stats=bool(rebalance_trials),
-            )
-        if compact:
-            plan = compact_stage(plan)  # ring steps have no free order
-        if autotune:
-            plan = autotune_oned_plan(plan, two_sided=(autotune == "fused"))
-        plan.hub = hub
-        seconds["decompose+pack"] = time.perf_counter() - t1
+        with span("tc.plan.pack", seconds, "decompose+pack"):
+            if best_plan is not None and step_masks:
+                plan = best_plan  # caller flags == trial flags
+            else:
+                plan = pack_oned_plan(
+                    g2, p, chunk=chunk, step_masks=step_masks,
+                    with_stats=bool(rebalance_trials),
+                )
+            if compact:
+                plan = compact_stage(plan)  # ring steps have no free order
+            if autotune:
+                plan = autotune_oned_plan(
+                    plan, two_sided=(autotune == "fused")
+                )
+            plan.hub = hub
         art_graph = g2
         if hub is not None:
             hub.aligned = rb is None or int(rb.get("best_seed", 0)) == 0

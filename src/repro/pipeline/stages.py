@@ -21,7 +21,6 @@ contract (padding fills, dtypes, orderings) is pinned by
 """
 from __future__ import annotations
 
-import time
 from typing import Optional, Tuple
 
 import numpy as np
@@ -780,11 +779,3 @@ def autotune_oned_plan(plan: OneDPlan, two_sided: bool = False) -> OneDPlan:
     return _dc.replace(
         plan, chunk=chunk, autotune=dict(report, n_long=None, d_small=None)
     )
-
-
-def timed(name: str, seconds: dict, fn, *args, **kwargs):
-    """Run one stage, recording its wall time under ``name``."""
-    t0 = time.perf_counter()
-    out = fn(*args, **kwargs)
-    seconds[name] = time.perf_counter() - t0
-    return out

@@ -247,6 +247,28 @@ def test_ckpt_refuses_cross_strategy_resume(tmp_path):
 
 
 # ======================================================================
+# names the trace reads: the engine's program and the count kernel
+# ======================================================================
+def test_engine_program_and_count_kernel_are_named():
+    """The device program is ``jit_tc_engine`` and the count kernel's ops
+    sit under ``tc_count``, as the tc_shift / tc_reduce scopes do."""
+    from repro.core import rmat
+    from repro.core.api import make_grid_mesh
+    from repro.core.cannon import build_cannon_fn
+    from repro.pipeline import PlanCache, plan_cannon
+
+    plan = plan_cannon(rmat(6, 8), 1, cache=PlanCache(maxsize=0)).plan
+    fn = build_cannon_fn(plan, make_grid_mesh(1))
+    arrays = plan.device_arrays()
+    text = fn.lower(**{k: arrays[k] for k in fn.shardings}).as_text(
+        debug_info=True
+    )
+    assert "\nmodule @jit_tc_engine " in text
+    assert "jit(tc_engine)/" in text
+    assert "/tc_count/" in text
+
+
+# ======================================================================
 # roofline: pairs-aware permutes + per-phase attribution
 # ======================================================================
 _HLO = """\
