@@ -11,8 +11,11 @@ Paths (DESIGN.md §2):
   path for hyper-sparse giant blocks.  On CPU a row-wise binary search:
   ``probe_shorter=True`` probes the shorter fragment into the longer (the
   paper's ⟨j,i,k⟩ hash-the-longer-list rule).  On TPU, where every binary
-  search step is a slow element gather, fragments are fetched as
-  contiguous windows and intersected by a dense equality compare.
+  search step is a slow element gather, each fragment is fetched as a
+  contiguous window: the lane rows it spans are gathered from the index
+  array viewed as ``(rows, 128)``, shifted left by the start's lane
+  offset, and intersected by a dense equality compare.  (A ``vmap`` of
+  ``lax.dynamic_slice`` would compile to one serial loop trip per task.)
 * ``tile``    — bit-packed 128×128 tile kernel (``repro.kernels.tc_tile``),
   wired in by :mod:`repro.core.cannon` when the plan carries tile stores.
 * ``fused``   — the Pallas probe-gather + intersection + accumulate
@@ -108,17 +111,49 @@ def _equality_intersect() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _window_rows(indptr, indices_padded, rows, dpad: int, sentinel: int):
+# lanes of one vreg row: the TPU path views each index array as (rows, 128)
+_LANES = 128
+
+
+def _lane_rows(dpad: int) -> int:
+    """Lane rows one ``dpad`` window spans, wherever it starts in a row."""
+    return -(-dpad // _LANES) + 1
+
+
+def _lane_view(indices, dpad: int, sentinel: int):
+    """``indices`` padded with ``sentinel`` to whole lane rows, plus
+    :func:`_lane_rows` spare rows so no window is clamped, as
+    ``(rows, 128)``."""
+    rows = -(-indices.shape[0] // _LANES) + _lane_rows(dpad)
+    pad = rows * _LANES - indices.shape[0]
+    return jnp.concatenate(
+        [indices, jnp.full((pad,), sentinel, indices.dtype)]
+    ).reshape(rows, _LANES)
+
+
+def _window_rows(indptr, lanes, rows, dpad: int, sentinel: int):
     """Like :func:`gather_rows`, but each fragment is one contiguous
-    ``dpad`` window of ``indices_padded`` (``indices`` followed by
-    ``dpad`` sentinels, so no window is clamped)."""
+    ``dpad`` window of the lane view ``lanes`` (:func:`_lane_view`): the
+    lane rows it spans, gathered whole and shifted left by the start's
+    lane offset.  (A ``vmap`` of ``lax.dynamic_slice`` over the flat
+    array compiles to a serial loop of one slice per task.)"""
     start = indptr[rows]
     length = indptr[rows + 1] - start
-    vals = jax.vmap(
-        lambda s: jax.lax.dynamic_slice(indices_padded, (s,), (dpad,))
-    )(start)
+    r = start // _LANES
+    panel = jnp.concatenate(
+        [jnp.take(lanes, r + c, axis=0) for c in range(_lane_rows(dpad))],
+        axis=1,
+    )
+    shift = start % _LANES
+    for bit in range(_LANES.bit_length() - 1):  # one roll per offset bit
+        panel = jnp.where(
+            ((shift >> bit) & 1)[:, None] == 1,
+            jnp.roll(panel, -(1 << bit), axis=1),
+            panel,
+        )
     offs = jnp.arange(dpad, dtype=indptr.dtype)
-    return jnp.where(offs[None, :] < length[:, None], vals, sentinel), length
+    valid = offs[None, :] < length[:, None]
+    return jnp.where(valid, panel[:, :dpad], sentinel), length
 
 
 def _searchsorted_rows(keys, queries):
@@ -171,12 +206,8 @@ def count_pair_search(
     offs = jnp.arange(dpad)
 
     if equality:
-        def tail(indices):
-            return jnp.concatenate(
-                [indices, jnp.full((dpad,), sentinel, indices.dtype)]
-            )
-
-        a_win, b_win = tail(a_indices), tail(b_indices)
+        a_win = _lane_view(a_indices, dpad, sentinel)
+        b_win = _lane_view(b_indices, dpad, sentinel)
 
     def one_chunk(acc, args):
         rows_i, rows_j, valid = args

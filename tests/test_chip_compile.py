@@ -11,6 +11,7 @@ module is imported), which skips where it cannot be described, and keeps
 all these compiles in this one file and this one process.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,12 @@ from jax.sharding import Mesh, SingleDeviceSharding
 # compile stays about a second
 NB20, NNZ20 = 1 << 20, 15_701_711
 FUSED_TASKS = 4096
+
+# the benchmark's q=1 blocks (bench/configs): nb, nnz (= tasks), longest row
+SEARCH_SHAPES = {
+    "kron-s16": (1 << 16, 909_538, 247),
+    "urand-s16": (1 << 16, 1_048_320, 30),
+}
 
 
 @pytest.fixture(scope="module")
@@ -111,3 +118,26 @@ def test_cannon_engine_compiles(topo, monkeypatch, q):
     assert compiled.memory_analysis().argument_size_in_bytes > 0
     if q > 1:
         assert "collective-permute" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", sorted(SEARCH_SHAPES))
+def test_search_fetch_has_no_per_task_loop(one_chip, monkeypatch, shape):
+    """``search``'s TPU formulation at the benchmark's block shapes
+    compiles to one ``while`` loop, the chunk scan: the window fetch is a
+    native gather, not a loop of one ``dynamic-slice`` per task (a
+    ``vmap`` of ``lax.dynamic_slice`` compiles to two such loops)."""
+    from repro.core import count
+
+    monkeypatch.setattr(count, "_equality_intersect", lambda: True)
+    nb, nnz, dpad = SEARCH_SHAPES[shape]
+    ptr = _sds((nb + 1,), jnp.int32, one_chip)
+    idx = _sds((nnz,), jnp.int32, one_chip)
+    fn = jax.jit(
+        lambda ap, ai, bp, bi, ti, tj, c: count.count_pair_search(
+            ap, ai, bp, bi, ti, tj, c, dpad=dpad, chunk=512
+        )
+    )
+    compiled = fn.lower(
+        ptr, idx, ptr, idx, idx, idx, _sds((), jnp.int32, one_chip)
+    ).compile()
+    assert len(re.findall(r"\swhile\(", compiled.as_text())) == 1
