@@ -301,6 +301,15 @@ def _stage_plan(ctx: RunContext, plan, mesh, fn) -> dict:
     return ctx.staged
 
 
+def _shared_engine(ctx: RunContext, key, build: Callable):
+    """The engine fn for ``key`` from the plan cache, built on a miss:
+    plans with one shape key share one traced and compiled program."""
+    from ..pipeline.cache import default_cache
+
+    cache = ctx.cache if ctx.cache is not None else default_cache()
+    return cache.memo(("engine",) + key, build)
+
+
 def _run_cannon(graph: Graph, mesh, ctx: RunContext):
     plan = ctx.plan  # a caller-supplied plan is already relabeled and
     if plan is None:  # wins over the pipeline (reorder/cyclic_p unused)
@@ -419,23 +428,29 @@ def _run_cannon(graph: Graph, mesh, ctx: RunContext):
         plan = bucketize_plan(plan)
 
     pod_axis = "pod" if ctx.npods > 1 else None
+    fn_key = (
+        "fn", mesh, ctx.method, ctx.probe_shorter, str(ctx.count_dtype),
+        pod_axis, ctx.use_step_mask, ctx.double_buffer, ctx.compact,
+        ctx.reduce_strategy, ctx.fused_impl, ctx.fused_tile,
+    )
     fn = ctx.memo(
-        ("fn", mesh, ctx.method, ctx.probe_shorter, str(ctx.count_dtype),
-         pod_axis, ctx.use_step_mask, ctx.double_buffer, ctx.compact,
-         ctx.reduce_strategy, ctx.fused_impl, ctx.fused_tile),
-        lambda: cannon_mod.build_cannon_fn(
-            plan,
-            mesh,
-            pod_axis=pod_axis,
-            method=ctx.method,
-            probe_shorter=ctx.probe_shorter,
-            count_dtype=ctx.count_dtype,
-            use_step_mask=ctx.use_step_mask,
-            double_buffer=ctx.double_buffer,
-            compact=ctx.compact,
-            reduce_strategy=ctx.reduce_strategy,
-            fused_impl=ctx.fused_impl,
-            fused_tile=ctx.fused_tile,
+        fn_key,
+        lambda: _shared_engine(
+            ctx, ("cannon", plan.shape_key()) + fn_key,
+            lambda: cannon_mod.build_cannon_fn(
+                plan,
+                mesh,
+                pod_axis=pod_axis,
+                method=ctx.method,
+                probe_shorter=ctx.probe_shorter,
+                count_dtype=ctx.count_dtype,
+                use_step_mask=ctx.use_step_mask,
+                double_buffer=ctx.double_buffer,
+                compact=ctx.compact,
+                reduce_strategy=ctx.reduce_strategy,
+                fused_impl=ctx.fused_impl,
+                fused_tile=ctx.fused_tile,
+            ),
         ),
     )
     if pod_axis is not None:

@@ -170,7 +170,7 @@ def build_cannon_fn(
         engine.check_fused_split(plan)
     kernel = make_csr_kernel(
         method,
-        dpad=plan.dmax,
+        dpad=plan.dpad,
         chunk=plan.chunk,
         probe_shorter=probe_shorter,
         count_dtype=count_dtype,
@@ -255,7 +255,7 @@ def build_cannon_stepper(
     )
     kernel = make_csr_kernel(
         method,
-        dpad=plan.dmax,
+        dpad=plan.dpad,
         chunk=plan.chunk,
         probe_shorter=probe_shorter,
         count_dtype=count_dtype,

@@ -13,8 +13,10 @@ its blocks:
 * ``m_*``  — the static task list: nonzeros ``(i, j)`` of ``U_{x, y}``.
 
 All ragged structures are padded to plan-wide maxima (XLA needs static
-shapes); the padding fractions are part of the plan report because they are
-*measured overhead* of the TPU adaptation (DESIGN.md §10.4).
+shapes), rounded up a coarse ladder (:func:`ladder_nnz`, :func:`ladder_dpad`)
+so that graphs of one size class share one compiled program; the padding
+fractions are part of the plan report because they are *measured overhead*
+of the TPU adaptation (DESIGN.md §10.4).
 
 The pre-skew implements Cannon's initial alignment at data-distribution
 time (the paper performs it as its first communication step; in an SPMD
@@ -43,6 +45,8 @@ __all__ = [
     "resolve_step_mask",
     "resolve_compact_steps",
     "host_aug_keys",
+    "ladder_nnz",
+    "ladder_dpad",
 ]
 
 
@@ -186,6 +190,51 @@ def resolve_step_mask(plan, use_step_mask) -> bool:
 
 INT = np.int32
 
+# plan-shape ladder: a block's padded length is a multiple of 2^(b - 7)
+# for b = ceil(log2 nnz) (< 1/64 added), a fragment's padded width a
+# multiple of 8 (one sublane tile) that is not a whole number of 128-lane
+# rows
+_NNZ_STEPS = 1 << 7
+_DPAD_STEP = 8
+_LANES = 128
+
+
+def ladder_nnz(nnz: int) -> int:
+    """Padded length of a block holding at most ``nnz`` entries: ``nnz``
+    rounded up to a multiple of ``2^(ceil(log2 nnz) - 7)``.
+
+    The static shapes of a Cannon plan come from this ladder, not from
+    the exact maxima, so graphs of one size class (the relabelings of
+    one graph among them, whose per-block maxima differ by a few
+    entries) share one compiled program."""
+    nnz = max(1, int(nnz))
+    step = max(1, (1 << (nnz - 1).bit_length()) // _NNZ_STEPS)
+    return -(-nnz // step) * step
+
+
+def ladder_dpad(dmax: int) -> int:
+    """The count kernels' padded fragment width for a longest fragment
+    of ``dmax``: rounded up to a multiple of 8, one step further where
+    that is a multiple of 128.
+
+    On a TPU v5e, ``search``'s kernel at a width of whole 128-lane rows
+    ran 2.1x slower at 128 than at 129 and 1.5x slower at 256 than at
+    248 (one block of ~896k tasks), so those widths are skipped:
+    fragments of 121 to 136 all pad to 136."""
+    dpad = -(-max(1, int(dmax)) // _DPAD_STEP) * _DPAD_STEP
+    return dpad + _DPAD_STEP if dpad % _LANES == 0 else dpad
+
+
+def ladder_task_share(nnz_max: int, tmax: int) -> float:
+    """Tasks the ladder adds per device, over the largest block's."""
+    return float(tmax / max(1, nnz_max) - 1.0)
+
+
+def ladder_dpad2_share(dmax: int) -> float:
+    """``dpad²`` work the ladder adds per task, over ``dmax²``."""
+    dmax = max(1, int(dmax))
+    return float((ladder_dpad(dmax) / dmax) ** 2 - 1.0)
+
 
 def host_aug_keys(
     indptr: np.ndarray, indices: np.ndarray
@@ -248,6 +297,11 @@ class PlanStats:
     # (DESIGN.md §4.7) can update the total exactly from dirty cells
     # alone; None on plans packed by the loop reference.
     itasks_per_cell: Optional[np.ndarray] = None  # (q, q, q) int64
+    # work the shape ladder adds (:func:`ladder_nnz`, :func:`ladder_dpad`):
+    # padded tasks over the largest block's, and the kernel's padded
+    # dpad² over dmax², each minus one
+    ladder_task_share: float = 0.0
+    ladder_dpad2_share: float = 0.0
 
 
 @dataclasses.dataclass
@@ -272,7 +326,7 @@ class TCPlan:
     m: int
     q: int  # square grid dimension (Cannon); SUMMA reuses q x q here
     nb: int  # local rows/cols per block = ceil(n / q)
-    nnz_pad: int  # padded nnz per block
+    nnz_pad: int  # padded nnz per block (ladder_nnz of the largest)
     tmax: int  # padded tasks per device
     dmax: int  # max adjacency-fragment length over all blocks
     chunk: int  # tasks per searchsorted chunk
@@ -322,6 +376,31 @@ class TCPlan:
     hub: Optional[object] = None
 
     # ------------------------------------------------------------------
+    @property
+    def dpad(self) -> int:
+        """The count kernels' padded fragment width: ``dmax`` up the
+        shape ladder (:func:`ladder_dpad`)."""
+        return ladder_dpad(self.dmax)
+
+    def shape_key(self) -> Tuple:
+        """Everything of the plan a compiled Cannon engine depends on:
+        the device arrays' shapes and dtypes and the kernel's static
+        parameters.  Plans with equal keys run one compiled program."""
+        hub = self.hub
+        return (
+            self.q,
+            self.dpad,
+            self.chunk,
+            self.n_long,
+            self.d_small,
+            self.compact.live_steps if self.compact is not None else None,
+            None if hub is None else (hub.dpad, hub.chunk, hub.sentinel),
+            tuple(
+                (k, v.shape, v.dtype.str)
+                for k, v in sorted(self.device_arrays().items())
+            ),
+        )
+
     def device_arrays(self) -> Dict[str, np.ndarray]:
         out = dict(
             a_indptr=self.a_indptr,
@@ -467,7 +546,8 @@ def _build_plan_loops(
     nb = -(-n // q)
     blocks = cyclic_blocks(graph, q, q)
 
-    nnz_pad = max(1, max(blocks[x][y].nnz for x in range(q) for y in range(q)))
+    nnz_max = max(blocks[x][y].nnz for x in range(q) for y in range(q))
+    nnz_pad = ladder_nnz(nnz_max)
     tmax = nnz_pad  # tasks per device == nnz of its mask block
 
     assert skew_perm is None or skew, "skew_perm is a Cannon-placement knob"
@@ -541,6 +621,8 @@ def _build_plan_loops(
             intersection_tasks_total=itasks,
             padding_fraction_indices=float(1.0 - m / max(1, tot_idx)),
             padding_fraction_tasks=float(1.0 - m / max(1, q * q * tmax)),
+            ladder_task_share=ladder_task_share(nnz_max, tmax),
+            ladder_dpad2_share=ladder_dpad2_share(dmax),
         )
 
     # per-(device, shift) skip mask — loop reference of the vectorized

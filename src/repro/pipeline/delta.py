@@ -51,6 +51,9 @@ from ..core.plan import (
     bucketize_plan,
     compact_live_steps,
     host_aug_keys,
+    ladder_dpad2_share,
+    ladder_nnz,
+    ladder_task_share,
 )
 from ..core.spans import span
 from .artifact import PlanArtifact
@@ -438,13 +441,14 @@ def _splice_cannon(
         pos_s * nb + li_s, minlength=nd * nb
     ).reshape(nd, nb)
 
-    # exact padded dims of a cold pack of g2: max nnz over *all* blocks
-    # (clean blocks keep their counts) — growing deltas widen the staged
-    # arrays, shrinking ones narrow them, so splice output stays
-    # byte-identical to a cold re-pack under the same σ
+    # the padded dims of a cold pack of g2: the ladder over the max nnz of
+    # *all* blocks (clean blocks keep their counts) — deltas that cross a
+    # ladder step widen or narrow the staged arrays, so splice output
+    # stays byte-identical to a cold re-pack under the same σ
     counts2_all = plan.m_cnt.astype(np.int64).copy()
     counts2_all[dirty_bids // q, dirty_bids % q] = counts_d
-    nnz_pad2 = max(1, int(counts2_all.max()))
+    nnz_max2 = int(counts2_all.max())
+    nnz_pad2 = ladder_nnz(nnz_max2)
     tmax2 = nnz_pad2
 
     starts_d = np.zeros(nd + 1, dtype=np.int64)
@@ -589,6 +593,8 @@ def _splice_cannon(
             padding_fraction_indices=float(1.0 - g2.m / max(1, tot_idx)),
             padding_fraction_tasks=float(1.0 - g2.m / max(1, q * q * tmax2)),
             itasks_per_cell=it_cell2,
+            ladder_task_share=ladder_task_share(nnz_max2, tmax2),
+            ladder_dpad2_share=ladder_dpad2_share(dmax2),
         )
         replanned.append("stats:dirty-cells")
 
@@ -650,7 +656,7 @@ def _splice_cannon(
     statics_changed = (
         live_grew
         or plan2.chunk != plan.chunk
-        or plan2.dmax != plan.dmax
+        or plan2.dpad != plan.dpad
         or plan2.n_long != plan.n_long
         or plan2.d_small != plan.d_small
     )

@@ -35,6 +35,9 @@ from ..core.plan import (
     TCPlan,
     compact_live_steps,
     host_aug_keys,
+    ladder_dpad2_share,
+    ladder_nnz,
+    ladder_task_share,
 )
 from ..core.preprocess import cyclic_relabel, degree_order
 from ..core.summa import SummaPlan
@@ -128,7 +131,7 @@ def _emit_tasks(
 
 
 def _tc_plan_stats(
-    coo: CyclicCOO, q: int, nnz_pad: int, tmax: int, m: int,
+    coo: CyclicCOO, q: int, nnz_pad: int, tmax: int, m: int, dmax: int,
     skew_perm: Optional[np.ndarray] = None,
 ):
     """Balance statistics (paper Tables 3/4 analogues) from the sorted
@@ -170,6 +173,8 @@ def _tc_plan_stats(
         padding_fraction_indices=float(1.0 - m / max(1, tot_idx)),
         padding_fraction_tasks=float(1.0 - m / max(1, q * q * tmax)),
         itasks_per_cell=it_cell,
+        ladder_task_share=ladder_task_share(coo.nnz_max, tmax),
+        ladder_dpad2_share=ladder_dpad2_share(dmax),
     )
 
 
@@ -228,13 +233,15 @@ def pack_tc_plan(
     ``skew_perm`` gathers through the σ visit order instead of the
     identity (:func:`choose_cannon_skew`); ``aug_keys`` emits the
     host-staged ``b_aug`` intersection keys for the placed B blocks.
+    Blocks are padded to :func:`~repro.core.plan.ladder_nnz` of the
+    largest one, so relabelings of a graph share the plan's shapes.
     """
     n, m = graph.n, graph.m
     assert skew_perm is None or skew, "skew_perm is a Cannon-placement knob"
     if coo is None:
         coo = cyclic_coo(graph, q, q)
     nb = coo.rows_loc
-    nnz_pad = max(1, coo.nnz_max)
+    nnz_pad = ladder_nnz(coo.nnz_max)
     tmax = nnz_pad
 
     sp = (
@@ -259,7 +266,7 @@ def pack_tc_plan(
     dmax = max(1, coo.row_len_max)
 
     stats = (
-        _tc_plan_stats(coo, q, nnz_pad, tmax, m, skew_perm=sp)
+        _tc_plan_stats(coo, q, nnz_pad, tmax, m, dmax, skew_perm=sp)
         if with_stats
         else None
     )
