@@ -81,11 +81,39 @@ class Graph:
 
     ``edges`` is an ``(m, 2)`` int64 array with ``edges[:, 0] < edges[:, 1]``
     (each undirected edge stored exactly once).
+
+    A ``Graph`` is immutable, ``edges`` included: construction freezes the
+    array, so writing to ``g.edges`` raises ``ValueError``.  An array that
+    owns its memory is frozen in place (the caller's reference too); a
+    view of a read-only base is kept as it is; a view of a writable
+    buffer is copied once, so a later write to that buffer does not reach
+    the graph.  An edit is a new ``Graph``, made with :meth:`from_edges`
+    or :meth:`relabel`.  This is what lets the plan cache hash a graph's
+    edges once (``repro.pipeline.cache.graph_digest``).
     """
 
     n: int
     edges: np.ndarray
     name: str = "graph"
+
+    def __post_init__(self):
+        edges = self.edges
+        if not _read_only(edges.base):  # a view of writable memory
+            edges = edges.copy()
+        edges.setflags(write=False)
+        object.__setattr__(self, "edges", edges)
+
+    def __getstate__(self):
+        # derived values memoized on the instance (the digest) stay behind:
+        # an unpickled array is writable again, so they are recomputed
+        return {k: v for k, v in self.__dict__.items() if k in _FIELDS}
+
+    @property
+    def frozen(self) -> bool:
+        """Whether ``edges`` is still read-only, through its whole base
+        chain; only code that turned writing back on with ``setflags``
+        can make it ``False``."""
+        return _read_only(self.edges)
 
     # ------------------------------------------------------------------
     # constructors
@@ -139,6 +167,24 @@ class Graph:
         a[self.edges[:, 0], self.edges[:, 1]] = 1
         a[self.edges[:, 1], self.edges[:, 0]] = 1
         return a
+
+
+_FIELDS = frozenset(f.name for f in dataclasses.fields(Graph))
+
+
+def _read_only(a) -> bool:
+    """Whether no one can write through ``a`` (an array, its base, or
+    ``None``) without first turning writing back on."""
+    while isinstance(a, np.ndarray):
+        if a.flags.writeable:
+            return False
+        a = a.base
+    if a is None:
+        return True
+    try:
+        return memoryview(a).readonly
+    except TypeError:
+        return False
 
 
 # ----------------------------------------------------------------------

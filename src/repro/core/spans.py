@@ -16,6 +16,8 @@ even when serving threads interleave.  The names, outermost first:
 * ``tc.plan`` — host work before the engine call: plan-cache lookup,
   planning on a miss, staging;
 * ``tc.plan.digest`` — the graph's content digest, on every call;
+  ``memo=1`` where the digest kept on the ``Graph`` was returned
+  (every graph of a batch), ``memo=0`` where edges were hashed;
 * ``tc.plan.relabel`` / ``.hubsplit`` / ``.rebalance`` / ``.pack`` /
   ``.delta`` — planning stages, on a plan-cache miss;
 * ``tc.stage`` — host→device upload of the plan arrays;
@@ -43,12 +45,17 @@ _NEXT_ID = itertools.count(1)
 
 
 @contextlib.contextmanager
-def span(name: str, seconds: Optional[Dict] = None, key: Optional[str] = None):
-    """Annotate the enclosed work as ``name``; with ``seconds``, also
-    write its wall time into ``seconds[key or name]``, exceptions
+def span(
+    name: str, seconds: Optional[Dict] = None, key: Optional[str] = None,
+    **meta,
+):
+    """Annotate the enclosed work as ``name``, with ``meta`` and the
+    count's ``count_id`` as the annotation's metadata; with ``seconds``,
+    also write its wall time into ``seconds[key or name]``, exceptions
     included."""
     cid = _COUNT_ID.get()
-    meta = {} if cid is None else {"count_id": cid}
+    if cid is not None:
+        meta["count_id"] = cid
     t0 = time.perf_counter()
     try:
         with jax.profiler.TraceAnnotation(name, **meta):

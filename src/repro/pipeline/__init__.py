@@ -6,7 +6,8 @@ Public surface:
   pipeline drivers (ingest → relabel → decompose → pack → stage)
   returning a :class:`PlanArtifact`.
 * :class:`PlanCache` / :func:`graph_digest` / :func:`default_cache` —
-  the content-addressed plan cache (§10.5).
+  the content-addressed plan cache (§10.5); :func:`digest_stats` counts
+  digests computed and kept digests returned.
 * :func:`count_triangles_many` — batched front-end: many graphs, one
   compiled engine call.
 * :mod:`.stages` — the individual stage functions (vectorized packers,
@@ -17,6 +18,7 @@ from .batch import ManyResult, count_triangles_many  # noqa: F401
 from .cache import (  # noqa: F401
     PlanCache,
     default_cache,
+    digest_stats,
     graph_digest,
     set_default_cache,
 )
@@ -43,6 +45,7 @@ __all__ = [
     "default_cache",
     "set_default_cache",
     "graph_digest",
+    "digest_stats",
     "plan_cannon",
     "plan_summa",
     "plan_oned",

@@ -23,7 +23,7 @@ import numpy as np
 from .. import compat
 from ..core.graph import Graph
 from ..core.spans import count_scope, launch, span
-from .cache import PlanCache, default_cache, graph_digest
+from .cache import PlanCache, default_cache, digest_memoized, graph_digest
 from .planner import relabel_cached
 from .stages import pack_oned_plan, pack_summa_plan, pack_tc_plan
 
@@ -308,7 +308,8 @@ def count_triangles_many(
             count_dtype = compat.default_count_dtype()
         cache = cache if cache is not None else default_cache()
 
-        with span("tc.plan.digest"):
+        memo = int(all(digest_memoized(g) for g in graphs))
+        with span("tc.plan.digest", memo=memo):
             digests = tuple(graph_digest(g) for g in graphs)
         key = (
             "many", schedule, method, mesh, q, chunk, reorder, cyclic_p,

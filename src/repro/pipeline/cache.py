@@ -4,6 +4,9 @@ Planning is addressed by *content*, not identity: the cache key starts
 with :func:`graph_digest` — a blake2b over the canonicalized edge set —
 so two structurally identical graphs hit the same entry no matter how
 they were constructed, and any edge edit changes the digest and misses.
+An edit is a new ``Graph`` (its ``edges`` are read-only), so the digest
+is computed once per ``Graph`` and kept on it: a warm count of a graph
+the caller holds does not hash its edges again (:func:`digest_stats`).
 The rest of the key is the full planning configuration supplied by the
 planner drivers: kind, grid, chunk, relabel options, mask/stat flags,
 ``rebalance_trials``, and the PR-5 stage knobs ``compact`` /
@@ -32,7 +35,36 @@ import numpy as np
 
 from ..core.graph import Graph
 
-__all__ = ["PlanCache", "graph_digest", "default_cache", "set_default_cache"]
+__all__ = [
+    "PlanCache",
+    "graph_digest",
+    "digest_memoized",
+    "digest_stats",
+    "default_cache",
+    "set_default_cache",
+]
+
+_MEMO = "_content_digest"  # the instance __dict__ key of the kept digest
+_DIGESTS = {"computed": 0, "memo_hits": 0}
+_DIGESTS_LOCK = threading.Lock()
+
+
+def _count_digest(which: str) -> None:
+    with _DIGESTS_LOCK:
+        _DIGESTS[which] += 1
+
+
+def digest_memoized(graph: Graph) -> bool:
+    """Whether :func:`graph_digest` would return ``graph``'s kept digest
+    without hashing: one was computed and the edges are still read-only."""
+    return _MEMO in graph.__dict__ and graph.frozen
+
+
+def digest_stats() -> dict:
+    """Process-wide digest counts: ``computed`` (edges hashed) and
+    ``memo_hits`` (a kept digest returned)."""
+    with _DIGESTS_LOCK:
+        return dict(_DIGESTS)
 
 
 def graph_digest(graph: Graph) -> str:
@@ -41,7 +73,25 @@ def graph_digest(graph: Graph) -> str:
     Canonicalizes via the packed key ``lo * n + hi`` (edges are already
     stored as ``(min, max)``) sorted ascending, so edge *order* never
     affects the digest — only the edge *set* and vertex count do.
+
+    The first call on a ``Graph`` keeps the digest on the instance; later
+    calls return it while the edges stay read-only.  A graph whose edges
+    were made writable again (or an unpickled one) is hashed on every
+    call, and loses what was kept.
     """
+    if digest_memoized(graph):
+        _count_digest("memo_hits")
+        return graph.__dict__[_MEMO]
+    graph.__dict__.pop(_MEMO, None)
+    frozen = graph.frozen
+    digest = _hash_edges(graph)
+    _count_digest("computed")
+    if frozen and graph.frozen:  # read-only all through the hash
+        graph.__dict__[_MEMO] = digest
+    return digest
+
+
+def _hash_edges(graph: Graph) -> str:
     n = np.int64(graph.n)
     key = graph.edges[:, 0] * n + graph.edges[:, 1]
     # Graph.from_edges emits keys already ascending (np.unique); only

@@ -16,7 +16,7 @@ from ..core.graph import Graph
 from ..core.plan import bucketize_plan
 from ..core.spans import span
 from .artifact import PlanArtifact
-from .cache import PlanCache, default_cache, graph_digest
+from .cache import PlanCache, default_cache, digest_memoized, graph_digest
 from .hubsplit import hubsplit_stage, normalize_hub_split
 from .rebalance import rebalance_stage
 from .stages import (
@@ -106,7 +106,8 @@ def _drive(kind, graph, key_tail, cache, pack):
     faultinject.fire("plan_stage", kind=kind)
     cache = cache if cache is not None else default_cache()
     seconds = {}
-    with span("tc.plan.digest", seconds, "ingest"):
+    memo = int(digest_memoized(graph))
+    with span("tc.plan.digest", seconds, "ingest", memo=memo):
         digest = graph_digest(graph)
 
     key = (kind, digest) + key_tail

@@ -1,6 +1,8 @@
 """Pipeline tests: vectorized packer byte-equivalence, plan-cache
 hit/miss semantics, cyclic-relabel round trip, and the batched
 front-end (``count_triangles_many``) against per-graph counts."""
+import pickle
+
 import numpy as np
 import pytest
 
@@ -19,6 +21,7 @@ from repro.core.preprocess import cyclic_relabel
 from repro.pipeline import (
     PlanCache,
     count_triangles_many as pipeline_many,
+    digest_stats,
     graph_digest,
     plan_cannon,
     plan_oned,
@@ -96,6 +99,81 @@ def test_graph_digest_is_content_addressed():
         np.concatenate([g.edges[:, 1], [g.n - 1]]),
     )
     assert graph_digest(g) != graph_digest(g_edit)
+
+
+def _digest_delta(before):
+    after = digest_stats()
+    return {k: after[k] - before[k] for k in after}
+
+
+def test_digest_is_computed_once_per_graph():
+    cache = PlanCache()
+    g = rmat(8, 8, seed=5)
+    before = digest_stats()
+    counts = {count_triangles(g, q=1, cache=cache).triangles for _ in range(3)}
+    assert _digest_delta(before) == {"computed": 1, "memo_hits": 2}
+    assert counts == {triangle_count_oracle(g)}
+    # the kept value is the content digest: an equal graph hashed afresh
+    twin = Graph.from_edges(g.n, g.edges[:, 0], g.edges[:, 1], name="twin")
+    before = digest_stats()
+    assert graph_digest(twin) == graph_digest(g)
+    assert _digest_delta(before) == {"computed": 1, "memo_hits": 1}
+
+
+def test_graph_edges_are_read_only():
+    g = rmat(6, 8, seed=6)
+    with pytest.raises(ValueError):
+        g.edges[0, 1] = 0
+    assert g.frozen
+
+
+def test_edges_made_writable_again_are_hashed_again():
+    cache = PlanCache()
+    g = rmat(7, 8, seed=7)
+    digest = graph_digest(g)
+    assert not count_triangles(g, q=1, cache=cache).artifact.cache_hit
+    present = {tuple(e) for e in g.edges.tolist()}
+    absent = next(
+        (u, v) for u in range(g.n) for v in range(u + 1, g.n)
+        if (u, v) not in present
+    )
+    g.edges.setflags(write=True)
+    g.edges[0] = absent
+    before = digest_stats()
+    assert graph_digest(g) != digest
+    assert _digest_delta(before) == {"computed": 1, "memo_hits": 0}
+    res = count_triangles(g, q=1, cache=cache)
+    assert not res.artifact.cache_hit
+    assert res.triangles == triangle_count_oracle(g)
+
+
+def test_graph_on_a_writable_view_keeps_its_own_edges():
+    g0 = rmat(7, 8, seed=8)
+    buf = np.array(g0.edges)  # writable, owns its memory
+    g = Graph(n=g0.n, edges=buf[:], name="view")
+    assert not np.shares_memory(g.edges, buf)
+    digest, tri = graph_digest(g), count_triangles(g, q=1).triangles
+    buf[:, 1] = buf[:, 0] + 1  # a later write to the caller's buffer
+    assert np.array_equal(g.edges, g0.edges)
+    assert graph_digest(g) == digest == graph_digest(g0)
+    assert count_triangles(g, q=1).triangles == tri == triangle_count_oracle(g0)
+
+
+def test_graph_on_a_read_only_view_is_not_copied():
+    g0 = rmat(7, 8, seed=9)
+    g = Graph(n=g0.n, edges=g0.edges[: g0.m // 2], name="prefix")
+    assert np.shares_memory(g.edges, g0.edges)
+    assert g.frozen and g.edges.base is g0.edges
+
+
+def test_unpickled_graph_is_hashed_on_every_call():
+    g = rmat(6, 8, seed=10)
+    digest = graph_digest(g)
+    g2 = pickle.loads(pickle.dumps(g))
+    assert g2.edges.flags.writeable and not g2.frozen
+    before = digest_stats()
+    assert graph_digest(g2) == graph_digest(g2) == digest
+    assert _digest_delta(before) == {"computed": 2, "memo_hits": 0}
 
 
 def test_plan_cache_hit_and_miss_semantics():

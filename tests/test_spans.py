@@ -3,7 +3,8 @@
 One traced session counts a small graph cold and warm, as a batch, as a
 delta, and once with a fault armed at ``device_stage``; the tests read the
 host spans back with the benchmark's own trace reader (``bench.trace``) and,
-for the ``count_id`` each span carries, with ``jax.profiler.ProfileData``.
+for the ``count_id`` and ``memo`` the spans carry, with
+``jax.profiler.ProfileData``.
 """
 from __future__ import annotations
 
@@ -76,20 +77,21 @@ def traced(tmp_path_factory):
         (o for o in host if o.name.startswith("tc.")),
         key=lambda o: (o.start_ns, -o.dur_ns),
     )
-    ids = _count_ids(str(path))
+    stats = _event_stats(str(path))
+    ids = {k: v.get("count_id") for k, v in stats.items()}
     tops = [o for o in host if o.name == "tc.count"]
     calls = {}
     for label, top in zip(("cold", "warm", "many", "delta", "fault"), tops):
         inside = [o for o in host if o is not top and _inside(o, top)]
         calls[label] = Call(top, inside, ids[(top.name, top.start_ns)])
-    return calls, results, ids, host
+    return calls, results, ids, host, stats
 
 
-def _count_ids(path):
-    """``count_id`` of every ``tc.*`` host event, by (name, start)."""
+def _event_stats(path):
+    """The metadata of every ``tc.*`` host event, by (name, start)."""
     from jax.profiler import ProfileData
 
-    ids = {}
+    out = {}
     with warnings.catch_warnings():
         # the stats' pybind type warns that it has no __module__
         warnings.simplefilter("ignore", DeprecationWarning)
@@ -97,13 +99,12 @@ def _count_ids(path):
             for line in plane.lines:
                 for e in line.events:
                     if e.name.startswith("tc."):
-                        stats = dict(e.stats)
-                        ids[(e.name, e.start_ns)] = stats.get("count_id")
-    return ids
+                        out[(e.name, e.start_ns)] = dict(e.stats)
+    return out
 
 
 def test_every_call_is_one_count_span(traced):
-    calls, _, _, host = traced
+    calls, _, _, host, _ = traced
     assert sum(o.name == "tc.count" for o in host) == 5
     # no program span lies outside a count
     tops = [c.top for c in calls.values()]
@@ -111,7 +112,7 @@ def test_every_call_is_one_count_span(traced):
 
 
 def test_cold_count_nests_the_plan_stages(traced):
-    calls, _, _, _ = traced
+    calls, _, _, _, _ = traced
     cold = calls["cold"]
     plan = cold.one("tc.plan")
     assert _inside(plan, cold.top)
@@ -120,11 +121,18 @@ def test_cold_count_nests_the_plan_stages(traced):
 
 
 def test_warm_count_hashes_and_plans_nothing(traced):
-    calls, _, _, _ = traced
+    calls, _, _, _, _ = traced
     warm = calls["warm"]
     assert _inside(warm.one("tc.plan.digest"), warm.one("tc.plan"))
     for name in MISS_ONLY:
         assert not warm.named(name), name
+
+
+@pytest.mark.parametrize("label,memo", [("cold", 0), ("warm", 1)])
+def test_digest_span_says_whether_the_kept_digest_served(traced, label, memo):
+    calls, _, _, _, stats = traced
+    digest = calls[label].one("tc.plan.digest")
+    assert stats[(digest.name, digest.start_ns)].get("memo") == memo
 
 
 @pytest.mark.parametrize("label", ["cold", "warm"])
@@ -136,7 +144,7 @@ def test_dispatch_wait_fetch_follow_the_plan(traced, label):
 
 
 def test_one_count_id_per_call(traced):
-    calls, _, ids, host = traced
+    calls, _, ids, host, _ = traced
     for call in calls.values():
         got = {ids[(s.name, s.start_ns)] for s in call.spans}
         assert got == {call.count_id}
@@ -146,7 +154,7 @@ def test_one_count_id_per_call(traced):
 
 @pytest.mark.parametrize("label", ["cold", "warm", "delta"])
 def test_preprocess_seconds_is_the_plan_span(traced, label):
-    calls, results, _, _ = traced
+    calls, results, _, _, _ = traced
     plan = calls[label].named("tc.plan")[-1]  # the count's own
     assert results[label].preprocess_seconds == pytest.approx(
         plan.dur_ns / 1e9, abs=1e-3
@@ -156,7 +164,7 @@ def test_preprocess_seconds_is_the_plan_span(traced, label):
 
 
 def test_batch_carries_the_spans(traced):
-    calls, results, _, _ = traced
+    calls, results, _, _, _ = traced
     many = calls["many"]
     plan = many.one("tc.plan")
     for name in ("tc.plan.digest",) + MISS_ONLY:
@@ -170,7 +178,7 @@ def test_batch_carries_the_spans(traced):
 
 
 def test_delta_carries_the_spans(traced):
-    calls, results, _, _ = traced
+    calls, results, _, _, _ = traced
     delta = calls["delta"]
     splice, count = delta.named("tc.plan")
     assert _inside(delta.one("tc.plan.delta"), splice)
@@ -182,7 +190,7 @@ def test_delta_carries_the_spans(traced):
 
 
 def test_a_fault_at_device_stage_still_closes_the_plan(traced):
-    calls, _, _, _ = traced
+    calls, _, _, _, _ = traced
     fault = calls["fault"]
     plan = fault.one("tc.plan")
     assert _inside(plan, fault.top) and plan.dur_ns > 0
