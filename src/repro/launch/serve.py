@@ -1,11 +1,6 @@
-"""Serving drivers.
+"""Triangle-count serving drivers.
 
-LM mode — batched KV-cached greedy decode for LM archs:
-
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b-smoke \
-        --batch 4 --gen 16
-
-Triangle-count mode — repeated batched counts over a working set of
+Batch mode — repeated batched counts over a working set of
 graphs, the heavy-traffic shape the planning pipeline is built for
 (content-addressed plan cache + one compiled engine call per batch;
 round 0 is the cold plan+compile, later rounds are pure dispatch):
@@ -13,7 +8,7 @@ round 0 is the cold plan+compile, later rounds are pure dispatch):
     PYTHONPATH=src python -m repro.launch.serve \
         "--tc-graphs" "rmat:10;rmat:10,8,1;karate" --grid 1 --rounds 5
 
-Triangle-count *streaming* mode — one live graph mutated by a random
+*Streaming* mode — one live graph mutated by a random
 edge delta per round, served through the incremental re-plan path
 (DESIGN.md §4.7; round 0 is the cold plan, later rounds splice dirty
 blocks and reuse the compiled engine):
@@ -232,13 +227,9 @@ def _maybe_verify(args, g, got):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--tc-graphs", default=None,
                     help="';'-separated graph specs: serve repeated "
-                         "batched triangle counts instead of an LM")
+                         "batched triangle counts")
     ap.add_argument("--tc-stream", default=None,
                     help="single graph spec: serve streaming counts — "
                          "one random edge delta per round through the "
@@ -269,49 +260,15 @@ def main():
                          "DESIGN.md §8) — exercises the per-request "
                          "retry/failure-budget path")
     args = ap.parse_args()
+    if not (args.tc_graphs or args.tc_stream):
+        ap.error("pass --tc-graphs or --tc-stream")
 
     from .compile_cache import configure_compile_cache
 
     configure_compile_cache()
     if args.tc_graphs:
         return _serve_tc(args)
-    if args.tc_stream:
-        return _serve_tc_stream(args)
-    if not args.arch:
-        raise SystemExit(
-            "pass --arch (LM serving), --tc-graphs, or --tc-stream"
-        )
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ..configs import get_config
-    from ..models.steps import build_lm_decode_step
-    from ..models.transformer import init_kv_cache, lm_init
-
-    cfg = get_config(args.arch)
-    assert cfg.family == "lm"
-    from .. import compat
-
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    params = lm_init(jax.random.key(0), cfg)
-    decode, _ = build_lm_decode_step(cfg, mesh)
-    cache = init_kv_cache(cfg, args.batch, args.max_len)
-    tok = jnp.ones((args.batch,), jnp.int32)
-    cache_len = jnp.zeros((args.batch,), jnp.int32)
-    outs = []
-    t0 = time.perf_counter()
-    for _ in range(args.gen):
-        tok, cache = decode(params, cache, tok, cache_len)
-        cache_len = cache_len + 1
-        outs.append(np.asarray(tok))
-    dt = time.perf_counter() - t0
-    print(
-        f"decoded {args.batch}x{args.gen} tokens in {dt:.2f}s "
-        f"({args.batch*args.gen/dt:.1f} tok/s)"
-    )
-    print("first sequence:", np.stack(outs, 1)[0])
+    return _serve_tc_stream(args)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,7 @@
 """Shared fixtures.  NOTE: device count is deliberately left at the
 default (1 CPU device) — multi-device tests spawn subprocesses with
 XLA_FLAGS=--xla_force_host_platform_device_count=N so smoke tests and
-benchmarks always see a single device (see launch/dryrun.py for the only
-512-device entry point)."""
+benchmarks always see a single device."""
 import os
 import subprocess
 import sys

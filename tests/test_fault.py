@@ -238,30 +238,30 @@ def test_check_partials_portable():
 # ----------------------------------------------------------------------
 # supervised_count: single-device recovery across schedules
 # ----------------------------------------------------------------------
-def test_supervised_count_recovers_every_point_inline():
+@pytest.mark.parametrize("schedule", ["cannon", "summa", "oned"])
+def test_supervised_count_recovers_every_point_inline(schedule):
     from repro.core import rmat, triangle_count_oracle
     from repro.runtime import FaultPlan, Supervisor
     from repro.runtime.supervisor import supervised_count
 
     g = rmat(8, 8, seed=3)
     exp = triangle_count_oracle(g)
-    for schedule in ("cannon", "summa", "oned"):
-        for compact in (None, False):
-            for spec in ("plan_stage", "device_stage", "step@0"):
-                sup = Supervisor(max_restarts=3)
-                res = supervised_count(
-                    g,
-                    supervisor=sup,
-                    fault_plan=FaultPlan.parse(spec),
-                    q=1,
-                    schedule=schedule,
-                    compact=compact,
-                )
-                key = (schedule, compact, spec)
-                assert res.triangles == exp, key
-                assert res.supervision["restarts"] == 1, key
-                assert not res.supervision["gave_up"], key
-                assert res.supervision["fault_log"], key
+    for compact in (None, False):
+        for spec in ("plan_stage", "device_stage", "step@0"):
+            sup = Supervisor(max_restarts=3)
+            res = supervised_count(
+                g,
+                supervisor=sup,
+                fault_plan=FaultPlan.parse(spec),
+                q=1,
+                schedule=schedule,
+                compact=compact,
+            )
+            key = (schedule, compact, spec)
+            assert res.triangles == exp, key
+            assert res.supervision["restarts"] == 1, key
+            assert not res.supervision["gave_up"], key
+            assert res.supervision["fault_log"], key
 
 
 def test_supervised_count_demotes_persistent_fused_fault():

@@ -5,6 +5,7 @@ it also runs in tier-1, where the Pallas body executes under the
 interpreter (single CPU device — see conftest).
 """
 import dataclasses
+import importlib
 
 import jax.numpy as jnp
 import numpy as np
@@ -88,7 +89,8 @@ def _random_csr(rng, nrows, maxd, n, pad=7):
     return jnp.asarray(indptr), jnp.asarray(idx)
 
 
-def test_short_panel_interpret_parity():
+@pytest.mark.parametrize("tcount", [0, 1, 250])
+def test_short_panel_interpret_parity(tcount):
     from repro.kernels.tc_fused.ref import fused_short_ref
     from repro.kernels.tc_fused.tc_fused import fused_short_counts
 
@@ -99,7 +101,6 @@ def test_short_panel_interpret_parity():
     ti = jnp.asarray(rng.integers(0, nrows, 300).astype(np.int32))
     tj = jnp.asarray(rng.integers(0, nrows, 300).astype(np.int32))
     # dense oracle over the same blocks
-    A = np.zeros((nrows, n)), np.asarray(ap), np.asarray(ai)
     dense = {}
     for tag, (ptr, idx) in (("a", (ap, ai)), ("b", (bp, bi))):
         m = np.zeros((nrows, n))
@@ -107,27 +108,24 @@ def test_short_panel_interpret_parity():
         for r in range(nrows):
             m[r, idx[ptr[r]:ptr[r + 1]]] = 1
         dense[tag] = m
-    for tcount in (0, 1, 250):
-        exp = int(
-            sum(
-                (dense["a"][i] * dense["b"][j]).sum()
-                for i, j in zip(
-                    np.asarray(ti)[:tcount], np.asarray(tj)[:tcount]
-                )
+    exp = int(
+        sum(
+            (dense["a"][i] * dense["b"][j]).sum()
+            for i, j in zip(np.asarray(ti)[:tcount], np.asarray(tj)[:tcount])
+        )
+    )
+    ref = int(
+        fused_short_ref(ap, ai, bp, bi, ti, tj, tcount, d=maxd, tile=32)
+    )
+    pal = int(
+        jnp.sum(
+            fused_short_counts(
+                ap, ai, bp, bi, ti, tj, tcount,
+                tile=32, d=maxd, interpret=True,
             )
         )
-        ref = int(
-            fused_short_ref(ap, ai, bp, bi, ti, tj, tcount, d=maxd, tile=32)
-        )
-        pal = int(
-            jnp.sum(
-                fused_short_counts(
-                    ap, ai, bp, bi, ti, tj, tcount,
-                    tile=32, d=maxd, interpret=True,
-                )
-            )
-        )
-        assert exp == ref == pal, (tcount, exp, ref, pal)
+    )
+    assert exp == ref == pal, (tcount, exp, ref, pal)
 
 
 def test_engine_fused_pallas_interpret_matches():
@@ -160,15 +158,17 @@ def test_fused_factory_requires_split_fields():
         )
 
 
-def test_plan_split_fields_are_real_dataclass_fields():
-    from repro.core.onedim import OneDPlan
-    from repro.core.plan import TCPlan
-    from repro.core.summa import SummaPlan
-
-    for cls in (TCPlan, SummaPlan, OneDPlan):
-        names = {f.name for f in dataclasses.fields(cls)}
-        assert {"n_long", "d_small"} <= names, cls
-    assert "bucket_stats" in {f.name for f in dataclasses.fields(TCPlan)}
+@pytest.mark.parametrize(
+    "module,name,fields",
+    [
+        ("repro.core.plan", "TCPlan", {"n_long", "d_small", "bucket_stats"}),
+        ("repro.core.summa", "SummaPlan", {"n_long", "d_small"}),
+        ("repro.core.onedim", "OneDPlan", {"n_long", "d_small"}),
+    ],
+)
+def test_plan_split_fields_are_real_dataclass_fields(module, name, fields):
+    cls = getattr(importlib.import_module(module), name)
+    assert fields <= {f.name for f in dataclasses.fields(cls)}, cls
 
 
 def test_two_sided_split_report():

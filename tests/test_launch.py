@@ -67,6 +67,15 @@ def test_serve_exit_code_counts_failed_requests(tmp_path, faults, failed):
     assert (out.returncode != 0) == (failed > 0), out.stderr[-2000:]
 
 
+def test_serve_without_mode_exits_with_usage(tmp_path):
+    """``serve`` with neither mode exits non-zero and names both."""
+    out = _run(["-m", "repro.launch.serve", "--rounds", "1"], tmp_path)
+    assert out.returncode != 0
+    assert "usage:" in out.stderr
+    assert "--tc-graphs" in out.stderr and "--tc-stream" in out.stderr
+    assert "triangles=" not in out.stdout
+
+
 def test_compile_cache_placed_from_env(tmp_path, monkeypatch):
     """With ``JAX_COMPILATION_CACHE_DIR`` set, ``tc_run`` writes its
     compiles there; unset, the cache goes to ``<checkout>/.jax_cache``."""

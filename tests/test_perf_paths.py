@@ -1,4 +1,4 @@
-"""Tests for the §Perf hillclimb code paths (H1a/H1b/H2)."""
+"""Tests for the §Perf hillclimb code paths (H1a/H1b)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -178,60 +178,6 @@ for kw in (dict(method="search", compress_lengths=True),
 print("OK")
 """
     assert "OK" in distributed_runner(code, ndev=4)
-
-
-def test_attention_seq_parallel_specs_numerically_equal():
-    """H2 constraints must not change results (1x1 mesh degenerate case)."""
-    from repro.configs import get_config
-    from repro.models.transformer import lm_init, lm_loss
-    from repro.models.steps import _inject_attn_specs
-
-    cfg = get_config("qwen2-0.5b-smoke")
-    from repro import compat
-
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
-    cfg2 = _inject_attn_specs(cfg, mesh)
-    params = lm_init(jax.random.key(0), cfg)
-    toks = jnp.ones((2, 32), jnp.int32)
-    l1, _ = lm_loss(params, cfg, toks, toks)
-    l2, _ = lm_loss(params, cfg2, toks, toks)
-    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-6)
-
-
-def test_causal_attention_vmap_matches_reference():
-    """Flash-style schedule vs plain softmax attention."""
-    from repro.models.attention import causal_attention
-
-    rng = np.random.default_rng(0)
-    b, s, h, kv, dh = 2, 64, 4, 2, 16
-    q = jnp.asarray(rng.normal(size=(b, s, h, dh)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, s, kv, dh)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, kv, dh)), jnp.float32)
-    out = causal_attention(q, k, v, q_chunk=16, kv_chunk=32)
-    # reference: dense masked softmax
-    g = h // kv
-    qg = q.reshape(b, s, kv, g, dh)
-    sc = jnp.einsum("bqkgd,bckd->bqkgc", qg, k) * (dh ** -0.5)
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    sc = jnp.where(mask[None, :, None, None, :], sc, -jnp.inf)
-    w = jax.nn.softmax(sc, axis=-1)
-    ref = jnp.einsum("bqkgc,bckd->bqkgd", w, v).reshape(b, s, h, dh)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), rtol=2e-3, atol=2e-3
-    )
-
-
-def test_causal_attention_nq_multiple():
-    from repro.models.attention import causal_attention
-
-    rng = np.random.default_rng(1)
-    b, s, h, dh = 1, 64, 2, 8
-    q = jnp.asarray(rng.normal(size=(b, s, h, dh)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(b, s, h, dh)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(b, s, h, dh)), jnp.float32)
-    a = causal_attention(q, k, v, q_chunk=64, kv_chunk=64, nq_multiple=1)
-    b_ = causal_attention(q, k, v, q_chunk=64, kv_chunk=64, nq_multiple=8)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-5, atol=1e-5)
 
 
 # NOTE: the hypothesis-based bucketed-probe property test lives in

@@ -40,22 +40,22 @@ def test_preprocess_u_rows_shrink():
     assert np.max(np.diff(u.indptr)) <= np.max(g.degrees())
 
 
-def test_cyclic_blocks_cover_all_edges():
+@pytest.mark.parametrize("r,c", [(2, 2), (3, 3), (2, 4)])
+def test_cyclic_blocks_cover_all_edges(r, c):
     g = rmat(8, 8, seed=2)
-    for r, c in [(2, 2), (3, 3), (2, 4)]:
-        blocks = cyclic_blocks(g, r, c)
-        total = sum(blocks[x][y].nnz for x in range(r) for y in range(c))
-        assert total == g.m
-        # ownership: each edge's block is (i % r, j % c)
-        for x in range(r):
-            for y in range(c):
-                blk = blocks[x][y]
-                rows = np.repeat(
-                    np.arange(blk.n_rows), np.diff(blk.indptr)
-                )
-                gi = rows * r + x
-                gj = blk.indices * c + y
-                assert np.all(gi < gj)  # U is strictly upper triangular
+    blocks = cyclic_blocks(g, r, c)
+    total = sum(blocks[x][y].nnz for x in range(r) for y in range(c))
+    assert total == g.m
+    # ownership: each edge's block is (i % r, j % c)
+    for x in range(r):
+        for y in range(c):
+            blk = blocks[x][y]
+            rows = np.repeat(
+                np.arange(blk.n_rows), np.diff(blk.indptr)
+            )
+            gi = rows * r + x
+            gj = blk.indices * c + y
+            assert np.all(gi < gj)  # U is strictly upper triangular
 
 
 def test_plan_balance_stats():
